@@ -10,26 +10,14 @@ axis conventions.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import threading
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-try:  # jax>=0.6 moved shard_map to jax.shard_map
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-#: jax < 0.6 calls the replication-check knob check_rep; newer jax check_vma.
-#: Every shard_map call site in the repo goes through this one shim:
-#: ``shard_map(f, ..., **{SM_CHECK_KW: False})``.
-SM_CHECK_KW = ("check_vma" if "check_vma"
-               in inspect.signature(shard_map).parameters else "check_rep")
-
 __all__ = ["use_mesh", "current_mesh", "mesh_axes", "dp_axes", "tp_axis",
-           "shard", "shard_batch_dim", "shard_map", "SM_CHECK_KW"]
+           "shard", "shard_batch_dim"]
 
 TP_AXIS = "model"
 

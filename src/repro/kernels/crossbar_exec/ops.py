@@ -15,9 +15,10 @@ def run_program(state: jnp.ndarray, microcode, backend: str = "jnp",
 
     Thin shim over the ``repro.pim.engine`` backend registry — ``"jnp"``
     (alias ``"scan"``, the lax.scan oracle), ``"unrolled"`` (static-index
-    variant), or ``"pallas"`` (interpret-mode TPU kernel on CPU; compiled
-    VMEM-tiled kernel on real TPU); ``engine.register_backend`` extends the
-    set without touching call sites.
+    variant), or ``"pallas"`` (the VMEM-tiled Pallas kernel: compiled on a
+    TPU, interpreted on the CPU backend — ``kernels.interpret_mode``);
+    ``engine.register_backend`` extends the set without touching call
+    sites.
     """
     from repro.pim import engine
 

@@ -14,10 +14,13 @@ revisiting-output pattern, VMEM footprint bm*bk + bk*bn (int8) + bm*bn
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 __all__ = ["quant_matmul_int"]
 
@@ -38,8 +41,10 @@ def _kernel(x_ref, w_ref, o_ref):
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def quant_matmul_int(x: jnp.ndarray, w: jnp.ndarray, bm: int = 128,
                      bn: int = 128, bk: int = 512,
-                     interpret: bool = True) -> jnp.ndarray:
-    """(M, K) int8 @ (K, N) int8 -> (M, N) int32, zero-padded to blocks."""
+                     interpret: Optional[bool] = None) -> jnp.ndarray:
+    """(M, K) int8 @ (K, N) int8 -> (M, N) int32, zero-padded to blocks.
+
+    ``interpret=None`` follows the platform (:func:`interpret_mode`)."""
     m, k = x.shape
     k2, n = w.shape
     assert k == k2
@@ -59,6 +64,6 @@ def quant_matmul_int(x: jnp.ndarray, w: jnp.ndarray, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w)
     return out[:m, :n]

@@ -56,7 +56,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import context as dctx
-from repro.dist.context import SM_CHECK_KW, shard_map
 from repro.dist.partitioning import tp_shard_dim
 from repro.kernels.quant_matmul.ops import quant_linear, quantize_sym
 from repro.kernels.quant_matmul.quant_matmul import quant_matmul_int
@@ -142,8 +141,9 @@ def _tp_forward(split: str, bits: int, x2, w):
             acc = quant_matmul_int(xq, wq, bm=bm, bn=bn, bk=bk)
             return acc.astype(jnp.float32) * xs[:, None] * ws[None, :]
 
-        y = shard_map(tile, mesh=mesh, in_specs=(P(dp, None), P(None, ax)),
-                      out_specs=P(dp, ax), **{SM_CHECK_KW: False})(x2, wp)
+        y = jax.shard_map(tile, mesh=mesh,
+                          in_specs=(P(dp, None), P(None, ax)),
+                          out_specs=P(dp, ax), check_vma=False)(x2, wp)
         return y[:, :n] if pn else y
 
     # row-parallel: only chosen when K % r == 0 (see tp_split), so the
@@ -162,8 +162,8 @@ def _tp_forward(split: str, bits: int, x2, w):
         acc = jax.lax.psum(quant_matmul_int(xq, wq, bm=bm, bn=bn, bk=bk), ax)
         return acc.astype(jnp.float32) * xs[:, None] * ws[None, :]
 
-    return shard_map(tile, mesh=mesh, in_specs=(P(dp, ax), P(ax, None)),
-                     out_specs=P(dp, None), **{SM_CHECK_KW: False})(x2, w)
+    return jax.shard_map(tile, mesh=mesh, in_specs=(P(dp, ax), P(ax, None)),
+                         out_specs=P(dp, None), check_vma=False)(x2, w)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

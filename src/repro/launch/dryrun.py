@@ -1,7 +1,9 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The ONLY entry point that forces 512 host devices — the env var must be set
-before jax initializes, hence the first two lines.  Each invocation handles
+The ONLY entry point that forces 512 host devices — the env vars must be set
+before jax initializes, hence the first lines.  It also pins the CPU
+platform, so on a machine with a TPU neither it nor its ``--all`` children
+(which inherit the environment) take the chip.  Each invocation handles
 one cell (isolates compiler failures); ``--all`` re-invokes itself per cell
 and aggregates the JSON results under ``results/dryrun/``.
 
@@ -16,6 +18,7 @@ from repro.xla_flags import ensure_host_device_count
 # Respect an existing device-count override (the test suite forces 8 via
 # conftest.py before jax initializes); only the standalone CLI wants 512.
 ensure_host_device_count(512)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

@@ -8,13 +8,9 @@ __all__ = ["make_mesh", "make_production_mesh", "make_local_mesh",
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them
-    (jax >= 0.5); plain construction on older releases."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=axis_types)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

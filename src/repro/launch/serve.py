@@ -73,13 +73,24 @@ import time
 import jax
 
 from repro.dist import context as dctx
+from repro.dist import partitioning as dpart
 from repro.launch.train import PRESETS, build_cfg
 from repro.models import model_lib as M
+from repro.models.layers import as_shapes
 from repro.pim import autotune, engine
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import ElasticMesh
 from repro.serving import (FailurePlan, Router, RouterConfig, Scheduler,
                            ServingConfig, synthetic_requests)
 from repro.serving.router import ROUTER_POLICIES
+
+
+def place_params(params, cfg, mesh):
+    """Lay ``params`` out over ``mesh`` as ``param_pspecs`` does (each
+    weight split on the "model" axis the way quant_tp's tiles expect), so
+    no step reshards the weights from the device they were made on."""
+    return jax.device_put(params, dpart.tree_shardings(
+        dpart.param_pspecs(as_shapes(M.param_specs(cfg)), mesh), mesh))
 
 
 def serve_trace(params, cfg, requests, *, max_batch: int, prompt_bucket: int,
@@ -220,6 +231,7 @@ def main():
                     help="inject a failure: kill replica R after router "
                          "step S (drain-and-requeue, then respawn)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     fleet = args.replicas > 1
     mesh = None
@@ -244,6 +256,8 @@ def main():
     cfg = cfg.scaled(max_seq_len=args.shared_prefix + args.prompt_len
                      + args.gen)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    if mesh is not None:
+        params = place_params(params, cfg, mesh)
     plens = sorted({max(1, args.prompt_len * f // 4) for f in (1, 2, 3, 4)})
     requests = synthetic_requests(
         args.requests, vocab_size=cfg.vocab_size, prompt_lens=plens,

@@ -27,6 +27,7 @@ from repro.models import model_lib as M
 from repro.models.layers import as_shapes
 from repro.optim.adamw import AdamWConfig, apply_updates, init_state
 from repro.pim import engine
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import (CheckpointManager, ElasticMesh,
                                            StragglerMonitor)
 
@@ -61,7 +62,9 @@ def build_cfg(args):
     return cfg
 
 
-def main():
+def main(argv=None):
+    """Train from command-line arguments (``argv``, default ``sys.argv``);
+    returns the per-step losses."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--preset", choices=list(PRESETS), default=None)
@@ -91,7 +94,8 @@ def main():
                          "cost-model report for every linear shape in the "
                          "model (picked configuration vs the engine "
                          "default; no timed trials)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # Single-device runs skip mesh machinery entirely; multi-device runs get
     # the largest valid (pod, data, model) mesh from whatever is alive.

@@ -196,7 +196,19 @@ def decode_attention(q, k_cache, v_cache, cache_len=None, *,
     which keeps the run causal and masks the rows the run itself just wrote
     for *later* queries.  The ``Sq = 1`` trace is unchanged by the
     generalization (same reshapes, same einsums, same broadcast mask).
+
+    A batch of one runs as two rows, for the reason ``layers.unembed``
+    gives: XLA lowers the size-1 batch to another reduction order, and a
+    row's output must not depend on how many rows share the step.
     """
+    if q.shape[0] == 1:
+        def dup(a):
+            return a if a is None or jnp.ndim(a) == 0 else \
+                jnp.concatenate([a, a])
+
+        return decode_attention(dup(q), dup(k_cache), dup(v_cache),
+                                dup(cache_len), window=window,
+                                valid=dup(valid))[:1]
     b, sq, h, d = q.shape
     _, s, hkv, _ = k_cache.shape
     n_rep = h // hkv

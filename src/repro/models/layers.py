@@ -157,8 +157,17 @@ def unembed(x, table, chunk: Optional[int] = None):
     einsum intermediate) peaks at ``chunk x d`` instead of ``V x d``.  The
     loss path threads ``ModelConfig.unembed_chunk`` here.  ``None`` (or a
     chunk >= V) is the single full-width einsum.
+
+    A single row runs as two: XLA (CPU and TPU alike) strength-reduces a
+    one-row product to multiply+reduce, which sums in another order than
+    the matmul any wider batch gets, so a max_batch=1 server would emit
+    other tokens than the same request batched with others.
     """
     V = table.shape[0]
+    if math.prod(x.shape[:-1]) == 1:
+        x2 = x.reshape(1, x.shape[-1])
+        return unembed(jnp.concatenate([x2, x2]), table,
+                       chunk)[:1].reshape(*x.shape[:-1], V)
     if chunk is None or chunk <= 0 or chunk >= V:
         return jnp.einsum("...d,vd->...v", x, table.astype(x.dtype))
     parts = [

@@ -641,6 +641,24 @@ def _unembed_table(params, cfg):
     return params.get("lm_head", params["embed"])
 
 
+def _vocab_logits(logits, cfg):
+    """f32 serving logits over the real vocabulary: the table's padding
+    rows (``cfg.padded_vocab``) are never a token, so greedy picks and the
+    scheduler's argmax cannot emit an id past ``cfg.vocab_size``."""
+    return logits[..., :cfg.vocab_size].astype(jnp.float32)
+
+
+def serve_jit(fn):
+    """``jax.jit`` for the serving entry points (prefill, decode, verify).
+
+    Excess precision is off: with it on, XLA may drop a bf16 rounding
+    between fused ops, and whether it does depends on the batch's shape —
+    on a TPU v5e a request's ``quant`` logits differed between a batch of
+    one and a batch of four.  Rounding after every op keeps each row's
+    result its own, whatever else shares the step."""
+    return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False})
+
+
 def _pim_ctx(cfg: ModelConfig):
     """Thread ``cfg.pim_mode`` into the trace (MaxText-style config
     threading): every ``linear`` below the entry point resolves against it.
@@ -749,7 +767,7 @@ def prefill(params, batch, cfg: ModelConfig, last_index=None, prefix=None):
             xl = jnp.take_along_axis(
                 x, last_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         logits = unembed(xl, _unembed_table(params, cfg))
-        return logits.astype(jnp.float32), caches
+        return _vocab_logits(logits, cfg), caches
 
 
 def prefill_packed(params, tokens, positions, segment_ids, last_index,
@@ -785,7 +803,7 @@ def prefill_packed(params, tokens, positions, segment_ids, last_index,
         # and bit-exactness vs unpacked is the packed path's contract
         logits = jnp.concatenate(
             [unembed(xl[i:i + 1], table) for i in range(xl.shape[0])], axis=0)
-        return logits.astype(jnp.float32), caches
+        return _vocab_logits(logits, cfg), caches
 
 
 def decode_step(params, token, pos, caches, cfg: ModelConfig):
@@ -796,8 +814,8 @@ def decode_step(params, token, pos, caches, cfg: ModelConfig):
         x, new_caches = _decoder_stack(params, x, cfg, positions=positions,
                                        mode="decode", caches=caches, pos=pos)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(x[:, -1],
-                         _unembed_table(params, cfg)).astype(jnp.float32)
+        logits = _vocab_logits(
+            unembed(x[:, -1], _unembed_table(params, cfg)), cfg)
         next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
         return next_tok, logits, new_caches
 
@@ -829,8 +847,8 @@ def decode_step_slots(params, tokens, pos, active, caches, cfg: ModelConfig,
                                        mode="decode", caches=caches, pos=pos,
                                        block_tables=block_tables)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(x[:, -1],
-                         _unembed_table(params, cfg)).astype(jnp.float32)
+        logits = _vocab_logits(
+            unembed(x[:, -1], _unembed_table(params, cfg)), cfg)
         next_tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
         next_tok = jnp.where(active[:, None], next_tok, 0)
         return next_tok, logits, new_caches
@@ -878,9 +896,9 @@ def decode_run_slots(params, tokens, pos, active, caches, cfg: ModelConfig,
         # — a (B*S, d) matmul picks a different reduction order than the
         # (B, d) rows the plain decode step runs, and bit-exactness vs
         # non-speculative decode is the verify pass's whole contract
-        logits = jnp.stack([unembed(x[:, i], table)
-                            for i in range(tokens.shape[1])],
-                           axis=1).astype(jnp.float32)
+        logits = _vocab_logits(jnp.stack([unembed(x[:, i], table)
+                                          for i in range(tokens.shape[1])],
+                                         axis=1), cfg)
         verify_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         verify_tok = jnp.where(active[:, None], verify_tok, 0)
         return verify_tok, logits, new_caches

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import context as dctx
-from repro.dist.context import SM_CHECK_KW as _SM_CHECK_KW
-from repro.dist.context import shard_map
 from repro.models.layers import activation
 
 
@@ -125,11 +123,11 @@ def moe_ffn(x, params: Dict, cfg) -> jnp.ndarray:
         raise ValueError("MoE experts are gated (SwiGLU) in all configs")
     w13_spec = P(tp, "data", None) if fsdp_gather else P(tp, None, None)
     w2_spec = P(tp, None, "data") if fsdp_gather else P(tp, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         ranked, mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None),
                   w13_spec, w2_spec, w13_spec),
         out_specs=P(dp, None),
-        **{_SM_CHECK_KW: False},
+        check_vma=False,
     )(xf, top_ids, top_w, params["w1"], params["w2"], params["w3"])
     return out.astype(x.dtype).reshape(b, s, d)

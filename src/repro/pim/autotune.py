@@ -351,8 +351,10 @@ def autotune_linear(tokens: int, d_in: int, d_out: int, *,
 
     Bit-identical integer accumulation is the PR 5 contract, so the pick
     is purely a speed decision: quant_tp only pays off once the mesh's
-    "model" axis is wide enough to beat its dispatch overhead.  Requires
-    an active mesh for quant_tp to differ from quant; runs eagerly jitted.
+    "model" axis is wide enough to beat its dispatch overhead.  Without an
+    active mesh whose "model" axis exceeds 1, quant_tp *is* quant (see
+    ``tp_quant_linear``), so only quant runs; any failure of a lowering
+    that does run propagates.  Runs eagerly jitted.
     """
     global _hits, _misses, _trials
     key = f"linear:t{_bucket_m(tokens)}d{d_in}o{d_out}"
@@ -366,18 +368,20 @@ def autotune_linear(tokens: int, d_in: int, d_out: int, *,
     import jax
     import jax.numpy as jnp
 
+    from repro.dist import context as dctx
     from repro.models import layers
 
+    mesh, ax = dctx.current_mesh(), dctx.tp_axis()
+    modes = ["quant"]
+    if mesh is not None and ax is not None and mesh.shape[ax] > 1:
+        modes.append("quant_tp")
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((tokens, d_in)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((d_in, d_out)), jnp.float32)
     timed: List[Tuple[float, str]] = []
-    for mode_name in ("quant", "quant_tp"):
+    for mode_name in modes:
         fn = jax.jit(lambda x, w, m=mode_name: layers.linear(x, w, mode=m))
-        try:
-            fn(x, w).block_until_ready()  # warm: trace + compile
-        except Exception:
-            continue  # no mesh / backend unavailable: not a candidate
+        fn(x, w).block_until_ready()  # warm: trace + compile
         times = []
         for _ in range(max(1, trials)):
             t0 = time.perf_counter()
@@ -386,10 +390,8 @@ def autotune_linear(tokens: int, d_in: int, d_out: int, *,
         timed.append((float(np.median(times)), mode_name))
         with _lock:
             _trials += 1
-    if not timed:
-        raise RuntimeError("no linear lowering could run (quant nor quant_tp)")
     t_best, best = min(timed)
-    t_default = next((t for t, nm in timed if nm == "quant"), t_best)
+    t_default = timed[0][0]     # "quant" always runs first
     plan = TunedPlan(key=key, kind="linear", model=best, n_cols=0, chunk=0,
                      backend=best, predicted_us=0.0, trial_us=t_best,
                      default_us=t_default, source="trial")

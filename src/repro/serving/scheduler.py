@@ -92,7 +92,6 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -358,19 +357,19 @@ class Scheduler:
             return M.decode_step_slots(p, tokens, pos, active, caches, cfg,
                                        block_tables=tables)
 
-        self._decode = jax.jit(_step)
-        self._prefill = jax.jit(
+        self._decode = M.serve_jit(_step)
+        self._prefill = M.serve_jit(
             lambda p, toks, li: M.prefill(p, {"tokens": toks}, cfg,
                                           last_index=li))
         # tail-resume prefill against a mapped prefix; retraces once per
         # (prefix length, tail bucket) shape pair — chunked prefill rides
         # the same jit (one trace per chunk boundary, covered by warmup)
-        self._prefill_resume = jax.jit(
+        self._prefill_resume = M.serve_jit(
             lambda p, toks, li, px: M.prefill(p, {"tokens": toks}, cfg,
                                               last_index=li, prefix=px))
         # packed prefill: one call covers a burst of short prompts;
         # retraces once per packed stream length (K is pinned to max_batch)
-        self._prefill_packed = jax.jit(
+        self._prefill_packed = M.serve_jit(
             lambda p, toks, pos, seg, li: M.prefill_packed(
                 p, toks, pos, seg, li, cfg))
 

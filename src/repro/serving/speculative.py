@@ -51,7 +51,6 @@ the scheduler's ``decode_traces`` does, and tests pin both to 1.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -118,8 +117,8 @@ class SpeculativeDecoder:
             return M.decode_run_slots(p, tokens, pos, active, caches,
                                       self.cfg, block_tables=tables)
 
-        self._draft = jax.jit(_draft_step)
-        self._verify = jax.jit(_verify_step)
+        self._draft = M.serve_jit(_draft_step)
+        self._verify = M.serve_jit(_verify_step)
 
     def run_round(self, params, tokens: np.ndarray, pos: np.ndarray,
                   active: np.ndarray, caches, tables):
@@ -137,12 +136,15 @@ class SpeculativeDecoder:
         b = tokens.shape[0]
         toks_run = np.zeros((b, self.k), np.int32)
         toks_run[:, 0] = tokens[:, 0]
-        cur = jnp.asarray(tokens)
         pos_j = jnp.asarray(pos)
         act_j = jnp.asarray(active)
         for i in range(1, self.k):
-            cur, _, caches = self._draft(params, cur, pos_j + (i - 1),
-                                         act_j, caches, tables)
+            # feed each draft step its token from the host copy: the draft
+            # step's own output can carry a mesh sharding (under quant_tp's
+            # "model" axis) that would retrace the draft jit on round one
+            cur, _, caches = self._draft(params, toks_run[:, i - 1:i],
+                                         pos_j + (i - 1), act_j, caches,
+                                         tables)
             toks_run[:, i] = np.asarray(cur)[:, 0]
         vt, _, caches = self._verify(params, jnp.asarray(toks_run), pos_j,
                                      act_j, caches, tables)

@@ -6,7 +6,6 @@ exercised everywhere (a mesh-free run would silently no-op every sharding
 constraint).  ``launch/dryrun.py`` detects the override and keeps it instead
 of forcing its standalone 512-device topology.
 """
-import importlib.util
 import os
 import sys
 
@@ -16,11 +15,6 @@ from repro.xla_flags import ensure_host_device_count  # noqa: E402
 
 ensure_host_device_count(8)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# Property-based tests prefer the real hypothesis; fall back to the bundled
-# deterministic shim when it is not installed (see tests/_compat).
-if importlib.util.find_spec("hypothesis") is None:
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_compat"))
 
 import pytest  # noqa: E402
 

@@ -40,7 +40,7 @@ def test_smoke_loss_prefill_decode(name):
     assert 0.0 < float(loss) < 20.0
 
     logits, caches = jax.jit(lambda p, b: M.prefill(p, b, cfg))(params, batch)
-    assert logits.shape == (B, cfg.padded_vocab)
+    assert logits.shape == (B, cfg.vocab_size)   # padding rows dropped
     assert np.isfinite(np.asarray(logits)).all()
 
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)

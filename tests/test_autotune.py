@@ -222,3 +222,30 @@ def test_autotune_linear_races_the_int8_lowerings():
     assert plan.trial_us > 0
     # cached on the second ask
     assert autotune.autotune_linear(4, 8, 8) is plan
+
+
+def test_autotune_linear_skips_quant_tp_only_without_a_model_axis():
+    """quant_tp is left out of the race exactly when no active mesh has a
+    "model" axis wider than 1 (it would *be* quant); with one, both run."""
+    from repro.dist import context as dctx
+    from repro.launch.mesh import make_mesh
+
+    plan = autotune.autotune_linear(4, 8, 8, trials=1)
+    assert plan.model == "quant"
+    assert autotune.table_info().trials == 1
+    with dctx.use_mesh(make_mesh((4,), ("model",))):
+        plan = autotune.autotune_linear(4, 8, 8, trials=1, force=True)
+    assert plan.model in ("quant", "quant_tp")
+    assert autotune.table_info().trials == 3
+
+
+def test_autotune_linear_propagates_a_failing_lowering(monkeypatch):
+    """A lowering that fails to run is an error, not a dropped candidate."""
+    from repro.models import layers
+
+    def broken(x, w, mode=None):
+        raise RuntimeError(f"{mode} lowering failed")
+
+    monkeypatch.setattr(layers, "linear", broken)
+    with pytest.raises(RuntimeError, match="quant lowering failed"):
+        autotune.autotune_linear(4, 8, 8, trials=1)

@@ -1,12 +1,10 @@
 """Control codec round-trips, including property-based random legal ops.
 
-The property tests run under real ``hypothesis`` when installed (CI pins
-it) and under the deterministic shim in ``tests/_compat`` otherwise; any
-strategy surface used here must exist in both (see the shim's docstring).
-Strategies deliberately cover the codable space edge-to-edge: all five
-gate types, arbitrary (non-power-of-two) periods up to ``k - 1``, range
-inits spanning partitions, and standard-model arbitrary partition
-subsets.
+The property tests run under ``hypothesis`` (pinned in
+requirements-ci.txt).  Strategies deliberately cover the codable space
+edge-to-edge: all five gate types, arbitrary (non-power-of-two) periods
+up to ``k - 1``, range inits spanning partitions, and standard-model
+arbitrary partition subsets.
 """
 import pytest
 from hypothesis import assume, given, settings, strategies as st

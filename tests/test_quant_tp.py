@@ -239,6 +239,38 @@ def test_serving_matches_quant_both_pools(small_model_config, paged):
         np.testing.assert_array_equal(out_q[ra], out_t[rb])
 
 
+def test_placed_weights_serve_quant_tp_like_quant(small_model_config):
+    """``serve.place_params`` splits the weights over the "model" axis
+    (none stays whole on the device it was made on), and quant_tp served
+    from them through ``serve_trace`` emits single-rank quant's tokens."""
+    import time
+
+    from repro.launch.serve import place_params, serve_trace
+    from repro.serving import synthetic_requests
+
+    cfg_q = small_model_config.scaled(pim_mode="quant")
+    params = M.init_params(cfg_q, jax.random.PRNGKey(0))
+    mesh = make_mesh((4,), ("model",))
+    placed = place_params(params, cfg_q, mesh)
+    leaves = jax.tree_util.tree_leaves(placed)
+    assert {len(a.sharding.device_set) for a in leaves} == {4}
+    assert any(a.addressable_shards[0].data.shape != a.shape for a in leaves)
+
+    def serve(p, cfg, mesh=None):
+        reqs = synthetic_requests(3, vocab_size=cfg.vocab_size,
+                                  prompt_lens=(4, 8), max_new_tokens=4,
+                                  seed=0, start_time=time.monotonic())
+        out, s = serve_trace(p, cfg, reqs, max_batch=2, prompt_bucket=8,
+                             mesh=mesh, paged=True, block_size=8)
+        assert s["decode_traces"] == 1
+        return [out[r.rid] for r in reqs]
+
+    with dctx.use_mesh(mesh):
+        got = serve(placed, cfg_q.scaled(pim_mode="quant_tp"), mesh)
+    for a, b in zip(got, serve(params, cfg_q)):
+        np.testing.assert_array_equal(a, b)
+
+
 # --------------------------------------------------------------------------
 # pspec plumbing for the sharded leaves
 # --------------------------------------------------------------------------

@@ -199,13 +199,84 @@ def test_run_raises_on_stalled_clock(cfg, params):
 def test_eos_stops_generation_early(cfg, params):
     probe = Scheduler(params, cfg, ServingConfig(max_batch=1))
     rid = probe.submit([5, 6, 7], 6)
-    full = probe.run()[rid]
-    eos = int(full[2])                           # third generated token
-    sched = Scheduler(params, cfg, ServingConfig(max_batch=1, eos_id=eos))
+    full = probe.run()[rid].tolist()
+    # the first generated token past the first that is new to the run:
+    # its first occurrence is where generation must stop, short of budget
+    stop = next(i for i in range(1, len(full) - 1)
+                if full[i] not in full[:i])
+    sched = Scheduler(params, cfg, ServingConfig(max_batch=1,
+                                                 eos_id=full[stop]))
     rid2 = sched.submit([5, 6, 7], 6)
     got = sched.run()[rid2]
-    assert got.tolist() == full[:3].tolist()
+    assert got.tolist() == full[:stop + 1]
+    assert len(got) < len(full)
     assert sched.metrics.requests[rid2].finish_time is not None
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_quant_decode_row_does_not_depend_on_batch_size(cfg, dtype):
+    """Under ``quant`` (per-row int8 linears) a request's decode logits
+    are bit-identical whether it steps alone or beside other rows, so a
+    max_batch=1 server emits the tokens a batched one does: the float
+    products left (attention, unembed) run a lone row as two, since XLA
+    lowers one-row products in another summation order."""
+    c = cfg.scaled(dtype=dtype, pim_mode="quant")
+    params = M.init_params(c, jax.random.PRNGKey(1))
+    prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6]], jnp.int32)
+    logits, caches = M.prefill(params, {"tokens": prompt}, c)
+    tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+    step = M.serve_jit(lambda p, t, pos, cc: M.decode_step(p, t, pos, cc, c))
+    rows = {}
+    for b in (1, 4):
+        cb = jax.tree.map(lambda a: jnp.concatenate([a] * b, axis=1), caches)
+        _, lg, _ = step(params, jnp.concatenate([tok] * b), jnp.int32(8), cb)
+        rows[b] = np.asarray(lg)
+    np.testing.assert_array_equal(rows[1][0], rows[4][0])
+
+
+def test_serve_jit_rounds_every_bf16_op():
+    """``serve_jit`` compiles without excess precision: a bf16 result is
+    rounded even where XLA fuses it into f32 work, as op-by-op execution
+    rounds it — so fusion choices, which follow the batch's shape, cannot
+    change a row's numbers.  A plain jit keeps the extra precision."""
+    def f(v):
+        return (v.astype(jnp.bfloat16) * 3).astype(jnp.float32) + 1
+
+    x = jnp.full((4, 8), 1 / 3, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(M.serve_jit(f)(x)),
+                                  np.asarray(f(x)))
+    assert not np.array_equal(np.asarray(jax.jit(f)(x)), np.asarray(f(x)))
+
+
+def test_one_row_unembed_matches_its_row_in_a_batch():
+    from repro.models.layers import unembed
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((4, 64)), jnp.bfloat16)
+    table = jnp.asarray(rng.standard_normal((1000, 64)), jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(unembed(x[:1], table))[0],
+                                  np.asarray(unembed(x, table))[0])
+
+
+def test_vocab_padding_rows_are_never_emitted(cfg):
+    """The embedding table pads the vocabulary up to ``padded_vocab`` rows
+    (qwen1.5-0.5b: 151936 -> 152064).  Serving logits cover the real
+    vocabulary only, so no greedy pick can land on a padding row."""
+    small = cfg.scaled(vocab_size=cfg.padded_vocab - 5,
+                       pad_vocab_multiple=8)
+    assert small.padded_vocab > small.vocab_size
+    params = M.init_params(small, jax.random.PRNGKey(0))
+    toks = jnp.asarray([[5, 6, 7]], jnp.int32)
+    logits, caches = M.prefill(params, {"tokens": toks}, small)
+    assert logits.shape[-1] == small.vocab_size
+    _, logits, _ = M.decode_step(params, toks[:, -1:], jnp.int32(3), caches,
+                                 small)
+    assert logits.shape[-1] == small.vocab_size
+    sched = Scheduler(params, small, ServingConfig(max_batch=2, paged=True,
+                                                   block_size=4))
+    rids = [sched.submit(list(range(1, 4 + i)), 6) for i in range(3)]
+    out = sched.run()
+    assert all(0 <= t < small.vocab_size for r in rids for t in out[r])
 
 
 # --------------------------------------------------------------------------

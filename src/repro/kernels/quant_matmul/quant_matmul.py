@@ -55,6 +55,10 @@ def quant_matmul_int(x: jnp.ndarray, w: jnp.ndarray, bm: int = 128,
         w = jnp.pad(w, ((0, pk), (0, pn)))
     mp, kp = x.shape
     _, np_ = w.shape
+    # the kernel's own name, which the compiled custom call carries
+    # (``%quant_matmul_int_kernel.1``) whatever jit encloses it; a profile
+    # finds the kernel by its ``quant_matmul_int`` prefix.  Not the jit's
+    # own name: a jaxpr would then hold two equations of that name.
     out = pl.pallas_call(
         _kernel,
         grid=(mp // bm, np_ // bn, kp // bk),
@@ -65,5 +69,6 @@ def quant_matmul_int(x: jnp.ndarray, w: jnp.ndarray, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
         interpret=interpret_mode(interpret),
+        name="quant_matmul_int_kernel",
     )(x, w)
     return out[:m, :n]

@@ -236,27 +236,27 @@ class CachePool:
                 mesh))
         self._assigned: set = set()   # occupied slots (stats only)
 
-        def assign(pool, request_cache, slot):
+        def pool_assign(pool, request_cache, slot):
             return jax.tree.map(
                 lambda c, r: jax.lax.dynamic_update_slice_in_dim(
                     c, r.astype(c.dtype), slot, axis=1),
                 pool, request_cache)
 
-        def evict(pool, slot):
+        def pool_evict(pool, slot):
             return jax.tree.map(
                 lambda c: jax.lax.dynamic_update_slice_in_dim(
                     c, jnp.zeros(c.shape[:1] + (1,) + c.shape[2:], c.dtype),
                     slot, axis=1),
                 pool)
 
-        def read(pool, slot):
+        def pool_read(pool, slot):
             return jax.tree.map(
                 lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1),
                 pool)
 
-        self._assign = jax.jit(assign)
-        self._evict = jax.jit(evict)
-        self._read = jax.jit(read)
+        self._assign = jax.jit(pool_assign)
+        self._evict = jax.jit(pool_evict)
+        self._read = jax.jit(pool_read)
 
     def assign(self, slot: int, request_cache) -> None:
         """Install a (batch-1) prefill cache into ``slot``."""
@@ -396,7 +396,7 @@ class PagedCachePool:
         window = cfg.sliding_window
         lcap, bs = self.lcap, block_size
 
-        def assign(pool, request_cache, table_row, slot, plen, start):
+        def pool_assign(pool, request_cache, table_row, slot, plen, start):
             def paged_leaf(c, rleaf):
                 # rleaf (ns, 1, cap_p, ...): dense positions 0..cap_p-1, or
                 # — windowed — position p at ring index p % cap_p.  ``start``
@@ -436,7 +436,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def assign_tail(pool, request_cache, table_row, slot, plen, m):
+        def pool_assign_tail(pool, request_cache, table_row, slot, plen, m):
             # tail-resume install: rleaf (ns, 1, cap_t, ...) holds dense
             # positions m..plen-1 at indices 0..plen-m-1 (the resumed
             # prefill emits the tail only, unpadded to capacity); the
@@ -471,7 +471,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def evict(pool, table_row, slot):
+        def pool_evict(pool, table_row, slot):
             out = {}
             for li, c in pool.items():
                 oc = {}
@@ -488,7 +488,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def read(pool, table_row, slot):
+        def pool_read(pool, table_row, slot):
             out = {}
             for li, c in pool.items():
                 oc = {}
@@ -503,7 +503,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def read_prefix(pool, blocks):
+        def pool_read_prefix(pool, blocks):
             # dense (ns, 1, nb*bs, ...) gather of the mapped prefix — the
             # ``prefix`` operand of the tail-resume prefill (one trace per
             # distinct prefix block count)
@@ -520,7 +520,7 @@ class PagedCachePool:
                     out[li] = oc
             return out
 
-        def cow(pool, src, dst):
+        def pool_cow(pool, src, dst):
             out = {}
             for li, c in pool.items():
                 oc = {}
@@ -534,7 +534,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def zero_block(pool, b):
+        def pool_zero_block(pool, b):
             out = {}
             for li, c in pool.items():
                 oc = {}
@@ -548,7 +548,7 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        def copy_state(pool, src, dst):
+        def pool_copy_state(pool, src, dst):
             # fork: duplicate the slot-indexed (non-paged) leaves of
             # ``src`` into ``dst``; paged leaves are shared by reference
             out = {}
@@ -565,14 +565,14 @@ class PagedCachePool:
                 out[li] = oc
             return out
 
-        self._assign = jax.jit(assign)
-        self._assign_tail = jax.jit(assign_tail)
-        self._evict = jax.jit(evict)
-        self._read = jax.jit(read)
-        self._read_prefix = jax.jit(read_prefix)
-        self._cow = jax.jit(cow)
-        self._zero_block = jax.jit(zero_block)
-        self._copy_state = jax.jit(copy_state)
+        self._assign = jax.jit(pool_assign)
+        self._assign_tail = jax.jit(pool_assign_tail)
+        self._evict = jax.jit(pool_evict)
+        self._read = jax.jit(pool_read)
+        self._read_prefix = jax.jit(pool_read_prefix)
+        self._cow = jax.jit(pool_cow)
+        self._zero_block = jax.jit(pool_zero_block)
+        self._copy_state = jax.jit(pool_copy_state)
 
     # ---- allocator ---------------------------------------------------
 

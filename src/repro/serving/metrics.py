@@ -3,8 +3,10 @@
 Per request the runtime records the standard serving quantities —
 TTFT (arrival to first token, which the scheduler emits at prefill) and
 TPOT (mean gap between subsequent tokens) — plus the aggregate
-tokens/second over the busy window and queue-depth samples taken once per
-scheduler step.  Everything is on the scheduler's injected clock, so tests
+tokens/second over the busy window and queue-depth and pool-occupancy
+samples taken once per scheduler step, kept as running aggregates (count,
+sum, largest value) so a long-running server's metrics stay constant in
+size.  Everything is on the scheduler's injected clock, so tests
 drive these deterministically with a fake clock.
 
 Under the multi-replica router each replica's scheduler keeps its own
@@ -59,14 +61,52 @@ class RequestMetrics:
         return self.admit_time - self.arrival_time
 
 
+#: what ``summary()`` reads of the pool samples: two peaks, two counters
+#: (their peak), and the two per-step ratios it averages
+_POOL_STATS = ("kv_bytes_in_use", "blocks_in_use", "blocks_shared",
+               "cow_copies", "occupancy", "frag")
+
+
+@dataclasses.dataclass
+class RunningStat:
+    """Count, sum and largest value of one per-step sample: what the
+    summary reads of it, in constant memory."""
+
+    n: int = 0
+    total: float = 0.0
+    peak: Optional[float] = None
+
+    def add(self, x: float) -> None:
+        self.n += 1
+        self.total += x
+        self._raise_peak(x)
+
+    def merge(self, other: "RunningStat") -> None:
+        self.n += other.n
+        self.total += other.total
+        if other.peak is not None:
+            self._raise_peak(other.peak)
+
+    def _raise_peak(self, x: float) -> None:
+        if self.peak is None or x > self.peak:
+            self.peak = x
+
+    def mean(self) -> float:
+        return self.total / self.n if self.n else math.nan
+
+    def max(self, default: float = math.nan) -> float:
+        return self.peak if self.peak is not None else default
+
+
 class ServingMetrics:
     """Aggregates RequestMetrics + queue-depth samples into a summary."""
 
     def __init__(self):
         self.requests: Dict[int, RequestMetrics] = {}
-        self.queue_depth_samples: List[int] = []
-        self.active_samples: List[int] = []
-        self.pool_samples: List[Dict[str, float]] = []
+        # per-step samples, by what the summary reads of each
+        self.queue_depth = RunningStat()
+        self.active = RunningStat()
+        self.pool = {k: RunningStat() for k in _POOL_STATS}
         self.tpot_samples: List[float] = []   # every inter-token gap
         self.deferred_admits = 0
         self.prefill_chunks = 0     # chunked-prefill calls (first + resumed)
@@ -114,8 +154,8 @@ class ServingMetrics:
         self.requests[rid].finish_time = now
 
     def sample_queue(self, depth: int, active: int) -> None:
-        self.queue_depth_samples.append(depth)
-        self.active_samples.append(active)
+        self.queue_depth.add(depth)
+        self.active.add(float(active))
 
     def sample_pool(self, stats: Dict[str, float],
                     tokens_live: float = math.nan) -> None:
@@ -129,7 +169,16 @@ class ServingMetrics:
         fragmentation (reserved-but-unwritten token slots inside allocated
         blocks; the logical reservation is the right denominator, a trie
         hit must not read as fragmentation)."""
-        self.pool_samples.append(dict(stats, tokens_live=tokens_live))
+        pool = self.pool
+        pool["kv_bytes_in_use"].add(stats["kv_bytes_in_use"])
+        pool["blocks_in_use"].add(stats["blocks_in_use"])
+        pool["blocks_shared"].add(stats.get("blocks_shared", 0.0))
+        pool["cow_copies"].add(stats.get("cow_copies", 0.0))
+        if stats["blocks_total"]:
+            pool["occupancy"].add(stats["blocks_in_use"]
+                                  / stats["blocks_total"])
+        if stats["tokens_reserved"] and not math.isnan(tokens_live):
+            pool["frag"].add(1.0 - tokens_live / stats["tokens_reserved"])
 
     def on_deferred_admit(self) -> None:
         """An arrived request stayed queued because the pool's free list
@@ -167,8 +216,9 @@ class ServingMetrics:
         A request drained from a killed replica appears in two parts: an
         unfinished entry on the dead replica and (eventually) a finished
         one on its new home.  The finished entry wins; among unfinished
-        duplicates the later-touched one does.  Samples concatenate and
-        ``deferred_admits`` sum — fleet-wide totals, not averages.
+        duplicates the later-touched one does.  Per-step aggregates combine,
+        gap samples concatenate and ``deferred_admits`` sum — fleet-wide
+        totals, not averages.
         """
         out = cls()
         for m in parts:
@@ -177,9 +227,10 @@ class ServingMetrics:
                 if cur is None or (cur.finish_time is None
                                    and r.finish_time is not None):
                     out.requests[rid] = r
-            out.queue_depth_samples.extend(m.queue_depth_samples)
-            out.active_samples.extend(m.active_samples)
-            out.pool_samples.extend(m.pool_samples)
+            out.queue_depth.merge(m.queue_depth)
+            out.active.merge(m.active)
+            for k, v in m.pool.items():
+                out.pool[k].merge(v)
             out.tpot_samples.extend(m.tpot_samples)
             out.deferred_admits += m.deferred_admits
             out.prefill_chunks += m.prefill_chunks
@@ -223,23 +274,10 @@ class ServingMetrics:
                  default=math.nan)
         t1 = max((r.finish_time for r in done), default=math.nan)
         busy = t1 - t0 if not (math.isnan(t0) or math.isnan(t1)) else math.nan
-        peak_bytes = max((p["kv_bytes_in_use"] for p in self.pool_samples),
-                         default=math.nan)
-        peak_blocks = max((p["blocks_in_use"] for p in self.pool_samples),
-                          default=math.nan)
-        occ = self._mean([p["blocks_in_use"] / p["blocks_total"]
-                          for p in self.pool_samples if p["blocks_total"]])
-        frag = self._mean(
-            [1.0 - p["tokens_live"] / p["tokens_reserved"]
-             for p in self.pool_samples
-             if p["tokens_reserved"] and not math.isnan(p["tokens_live"])])
+        pool = self.pool
         admitted = [r for r in rs if r.admit_time is not None]
         hits = [r for r in admitted if r.prefix_tokens > 0]
         misses = [r for r in admitted if r.prefix_tokens == 0]
-        peak_shared = max((p.get("blocks_shared", 0.0)
-                           for p in self.pool_samples), default=0.0)
-        cow = max((p.get("cow_copies", 0.0)
-                   for p in self.pool_samples), default=0.0)
         out = {
             "n_requests": len(rs),
             "n_finished": len(done),
@@ -254,16 +292,15 @@ class ServingMetrics:
             "p99_tpot_s": self._p99(self.tpot_samples),
             "mean_queue_wait_s": self._mean([r.queue_wait for r in rs]),
             "p50_queue_wait_s": self._p50([r.queue_wait for r in rs]),
-            "max_queue_depth": max(self.queue_depth_samples, default=0),
-            "mean_active_slots": self._mean(
-                [float(a) for a in self.active_samples]),
+            "max_queue_depth": self.queue_depth.max(default=0),
+            "mean_active_slots": self.active.mean(),
             # cache-pool occupancy (sampled once per scheduler step):
             # peak bytes is the headline paged-vs-contiguous comparison —
             # the contiguous pool reports its static reservation here.
-            "peak_kv_bytes": peak_bytes,
-            "peak_pool_blocks": peak_blocks,
-            "mean_block_occupancy": occ,
-            "mean_internal_frag": frag,
+            "peak_kv_bytes": pool["kv_bytes_in_use"].max(),
+            "peak_pool_blocks": pool["blocks_in_use"].max(),
+            "mean_block_occupancy": pool["occupancy"].mean(),
+            "mean_internal_frag": pool["frag"].mean(),
             "deferred_admits": self.deferred_admits,
             "prefill_chunks": self.prefill_chunks,
             "packed_prefills": self.packed_prefills,
@@ -293,8 +330,8 @@ class ServingMetrics:
                                               for r in admitted)),
             "mean_ttft_hit_s": self._mean([r.ttft for r in hits]),
             "mean_ttft_miss_s": self._mean([r.ttft for r in misses]),
-            "peak_blocks_shared": peak_shared,
-            "cow_copies": cow,
+            "peak_blocks_shared": pool["blocks_shared"].max(default=0.0),
+            "cow_copies": pool["cow_copies"].max(default=0.0),
         }
         if self.router_policy is not None:
             out.update({

@@ -80,6 +80,19 @@ unfinished :class:`Request`s for requeue elsewhere) and ``output(rid)``
 (harvest finished tokens).  Everything fleet-shaped — dispatch,
 health, respawn — lives one level up in :mod:`repro.serving.router`.
 
+**Spans.** ``step()`` and its parts are ``jax.profiler`` trace
+annotations, all named ``serve.*`` (``serve.step``, ``serve.admit``,
+``serve.prefill``, ``serve.decode``, ``serve.pool.evict``, ...).  A
+``serve.prefill`` is one prefill program call: its dispatch, and the
+read-back of the first token where the call yields one; the pool's reads
+and writes around it are spans of their own.  While a profiler records, a
+span of one request carries its ``rid``, and spans carry their counts
+(``tokens``, ``bucket``, ``rows``, ``blocks``) as arguments, for reading
+a trace by hand.  A span costs a microsecond or so while no profiler
+runs, and lands in a profile on the device's clock beside the programs,
+which are named after their functions (``jit_serve_decode``,
+``jit_serve_prefill``, ``jit_pool_*``).
+
 Under ``pim_mode="pim_sim"`` the decode step's
 crossbar GEMMs
 run through the engine's persistent :class:`ExecutionSession` pool:
@@ -94,6 +107,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.models import model_lib as M
 from repro.models.config import ModelConfig
@@ -109,6 +123,15 @@ __all__ = ["ServingConfig", "Scheduler"]
 #: what keeps packing bit-exact.  Far above any short-prompt burst worth
 #: packing anyway — longer prompts chunk instead.
 _PACK_MAX_TOKENS = 512
+
+
+def _span(name: str, args=None) -> TraceAnnotation:
+    """A ``serve.*`` profiler span.  ``args``, a function that gives the
+    span's arguments, runs only while a profiler records: they are for
+    reading a trace by hand, and cost nothing otherwise."""
+    if args is not None and TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args())
+    return TraceAnnotation(name)
 
 
 def _idle_sleep(clock, arrival: float, stalls: int,
@@ -337,6 +360,7 @@ class Scheduler:
         # it defers again after an interloper
         self._deferred_rids: set = set()
         self._plain_decode_traces = 0   # retraces of the plain (B, 1) jit
+        self._step_num = 0              # ``serve.step`` spans so far
 
         # speculative decode: active only when a round can beat plain
         # decode — draft_k=1 drafts nothing (the verify step *is* plain
@@ -351,27 +375,33 @@ class Scheduler:
             self._spec = SpeculativeDecoder(cfg, scfg.draft_mode,
                                             scfg.draft_k)
 
-        def _step(p, tokens, pos, active, caches, tables):
+        # each program is named after its function (``jit_serve_decode``
+        # in a profile), so a trace gives device time to it by name
+        def serve_decode(p, tokens, pos, active, caches, tables):
             # tables is None (an empty pytree to jit) for the contiguous pool
             self._plain_decode_traces += 1
             return M.decode_step_slots(p, tokens, pos, active, caches, cfg,
                                        block_tables=tables)
 
-        self._decode = M.serve_jit(_step)
-        self._prefill = M.serve_jit(
-            lambda p, toks, li: M.prefill(p, {"tokens": toks}, cfg,
-                                          last_index=li))
+        def serve_prefill(p, toks, li):
+            return M.prefill(p, {"tokens": toks}, cfg, last_index=li)
+
         # tail-resume prefill against a mapped prefix; retraces once per
         # (prefix length, tail bucket) shape pair — chunked prefill rides
         # the same jit (one trace per chunk boundary, covered by warmup)
-        self._prefill_resume = M.serve_jit(
-            lambda p, toks, li, px: M.prefill(p, {"tokens": toks}, cfg,
-                                              last_index=li, prefix=px))
+        def serve_prefill_resume(p, toks, li, px):
+            return M.prefill(p, {"tokens": toks}, cfg, last_index=li,
+                             prefix=px)
+
         # packed prefill: one call covers a burst of short prompts;
         # retraces once per packed stream length (K is pinned to max_batch)
-        self._prefill_packed = M.serve_jit(
-            lambda p, toks, pos, seg, li: M.prefill_packed(
-                p, toks, pos, seg, li, cfg))
+        def serve_prefill_packed(p, toks, pos, seg, li):
+            return M.prefill_packed(p, toks, pos, seg, li, cfg)
+
+        self._decode = M.serve_jit(serve_decode)
+        self._prefill = M.serve_jit(serve_prefill)
+        self._prefill_resume = M.serve_jit(serve_prefill_resume)
+        self._prefill_packed = M.serve_jit(serve_prefill_packed)
 
     # ------------------------------------------------------------------
 
@@ -475,8 +505,34 @@ class Scheduler:
         rid = int(self._slot_rid[slot])
         self.metrics.on_finish(rid, now)
         self._active_req.pop(rid, None)
-        self._slot_rid[slot] = -1
-        self.pool.evict(slot)
+        self._evict(slot)
+
+    def _pool_admit(self, slot: int, req: Request, cache, plen: int,
+                    **kw) -> None:
+        """Install ``req``'s prefill cache of ``plen`` positions into
+        ``slot``, reserving the request's whole block need (the
+        contiguous pool's unit is the whole slot)."""
+        n_tok = req.prompt.shape[0] + req.max_new_tokens
+        with _span("serve.pool.admit", lambda: dict(
+                rid=req.rid, blocks=(self.pool.blocks_needed(n_tok)
+                                     if self.pool.paged else 1))):
+            self.pool.admit(slot, cache, plen, n_tok, **kw)
+
+    def _evict(self, slot: int) -> None:
+        """Free ``slot`` and return its blocks to the pool."""
+        with _span("serve.pool.evict", lambda: dict(
+                rid=int(self._slot_rid[slot]),
+                blocks=(len(self.pool.slot_blocks(slot))
+                        if self.pool.paged else 1))):
+            self._slot_rid[slot] = -1
+            self.pool.evict(slot)
+
+    @staticmethod
+    def _first_tokens(logits) -> np.ndarray:
+        """Greedy first tokens of a prefill, read back to the host (this
+        waits for the prefill to run)."""
+        with _span("serve.prefill.wait"):
+            return np.asarray(jnp.argmax(logits, -1))
 
     def _dense_prefill_ok(self, plen: int) -> bool:
         """Whether chunked/packed prefill may serve a ``plen`` prompt: a
@@ -565,11 +621,14 @@ class Scheduler:
             pos[s0:s0 + w] = np.arange(w)
             seg[s0:s0 + plen] = i
             last[i] = s0 + plen - 1
-        logits, cache = self._prefill_packed(
-            self.params, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(seg), jnp.asarray(last))
+        with _span("serve.prefill", lambda: dict(
+                rid=-1, tokens=sum(r.prompt.shape[0] for r in pack),
+                bucket=L)):
+            logits, cache = self._prefill_packed(
+                self.params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(seg), jnp.asarray(last))
+            firsts = self._first_tokens(logits)
         self.metrics.on_packed_prefill()
-        firsts = np.asarray(jnp.argmax(logits, -1))
         now = self.clock()
         for i, r in enumerate(pack):
             plen = r.prompt.shape[0]
@@ -583,9 +642,9 @@ class Scheduler:
                 self.metrics.on_finish(r.rid, now)
                 continue
             slot = int(free.pop(0))
-            self.pool.admit(slot, cache, plen, plen + r.max_new_tokens,
-                            prompt=r.prompt if self._prefix_on else None,
-                            start=int(starts[i]))
+            self._pool_admit(slot, r, cache, plen,
+                             prompt=r.prompt if self._prefix_on else None,
+                             start=int(starts[i]))
             self._slot_rid[slot] = r.rid
             self._active_req[r.rid] = r
             self._tokens[slot, 0] = first
@@ -600,27 +659,21 @@ class Scheduler:
         front (later chunks extend in place, they never allocate), so a
         mid-prefill slot can always finish without deferring."""
         chunk = self.scfg.prefill_chunk
-        plen = req.prompt.shape[0]
-        n_tok = plen + req.max_new_tokens
-        if m:
-            bucket = self._bucket_tail(chunk, m)
-            toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
-            toks[0, :chunk] = req.prompt[m:m + chunk]
-            _, cache = self._prefill_resume(
-                self.params, jnp.asarray(toks),
-                jnp.asarray([chunk - 1], jnp.int32),
-                self.pool.read_prefix(pblocks))
-            # prompt=None: a half-written prompt must not enter the trie —
-            # registration is deferred to the last chunk
-            self.pool.admit(slot, cache, m + chunk, n_tok,
-                            prefix_blocks=pblocks)
-        else:
-            bucket = self._bucket(chunk)
-            toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
-            toks[0, :chunk] = req.prompt[:chunk]
-            _, cache = self._prefill(self.params, jnp.asarray(toks),
-                                     jnp.asarray([chunk - 1], jnp.int32))
-            self.pool.admit(slot, cache, chunk, n_tok)
+        bucket = self._bucket_tail(chunk, m) if m else self._bucket(chunk)
+        toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
+        toks[0, :chunk] = req.prompt[m:m + chunk]
+        li = jnp.asarray([chunk - 1], jnp.int32)
+        prefix = self.pool.read_prefix(pblocks) if m else None
+        with _span("serve.prefill", lambda: dict(
+                rid=req.rid, tokens=chunk, bucket=bucket)):
+            if m:
+                _, cache = self._prefill_resume(
+                    self.params, jnp.asarray(toks), li, prefix)
+            else:
+                _, cache = self._prefill(self.params, jnp.asarray(toks), li)
+        # prompt=None: a half-written prompt must not enter the trie —
+        # registration is deferred to the last chunk
+        self._pool_admit(slot, req, cache, m + chunk, prefix_blocks=pblocks)
         self.metrics.on_admit(req.rid, self.clock(), prefix_tokens=m)
         self.metrics.on_prefill_chunk()
         self._slot_rid[slot] = req.rid
@@ -648,18 +701,21 @@ class Scheduler:
         toks[0, :tlen] = req.prompt[done:new_len]
         prefix = self.pool.read_prefix(
             self.pool.slot_blocks(slot)[:done // self.pool.block_size])
-        logits, cache = self._prefill_resume(
-            self.params, jnp.asarray(toks),
-            jnp.asarray([tlen - 1], jnp.int32), prefix)
+        with _span("serve.prefill", lambda: dict(
+                rid=req.rid, tokens=tlen, bucket=bucket)):
+            logits, cache = self._prefill_resume(
+                self.params, jnp.asarray(toks),
+                jnp.asarray([tlen - 1], jnp.int32), prefix)
+            # the last chunk reads the first token (the request's TTFT)
+            first = (int(self._first_tokens(logits)[0])
+                     if new_len == plen else None)
         self.pool.extend(slot, cache, done, new_len)
         self.metrics.on_prefill_chunk()
         self._prefill_done[slot] = new_len
         self._pos[slot] = new_len
-        if new_len < plen:
+        if first is None:
             return
-        # prompt fully cached: first token (the request's TTFT) and into
-        # the decode batch
-        first = int(np.asarray(jnp.argmax(logits, -1))[0])
+        # prompt fully cached: into the decode batch
         now = self.clock()
         self.metrics.on_token(req.rid, now)
         self._outputs[req.rid] = [first]
@@ -667,10 +723,7 @@ class Scheduler:
         self._prefilling[slot] = False
         self._prefill_done[slot] = 0
         if req.max_new_tokens <= 1 or first == self.scfg.eos_id:
-            self.metrics.on_finish(req.rid, now)
-            self._active_req.pop(req.rid, None)
-            self._slot_rid[slot] = -1
-            self.pool.evict(slot)
+            self._finish(slot, now)
             return
         if self._prefix_on:
             # deferred trie registration (admit passed prompt=None)
@@ -690,14 +743,15 @@ class Scheduler:
         spent = 0
         budget = self.scfg.step_token_budget
         chunk = self.scfg.prefill_chunk
-        for slot in np.flatnonzero(self._prefilling):
-            req = self._active_req[int(self._slot_rid[slot])]
-            done = int(self._prefill_done[slot])
-            tlen = min(chunk, req.prompt.shape[0] - done)
-            if budget is not None and spent and spent + tlen > budget:
-                break
-            self._chunk_step(int(slot), req, done, tlen, emitted)
-            spent += tlen
+        with _span("serve.prefill_chunks"):
+            for slot in np.flatnonzero(self._prefilling):
+                req = self._active_req[int(self._slot_rid[slot])]
+                done = int(self._prefill_done[slot])
+                tlen = min(chunk, req.prompt.shape[0] - done)
+                if budget is not None and spent and spent + tlen > budget:
+                    break
+                self._chunk_step(int(slot), req, done, tlen, emitted)
+                spent += tlen
         return spent
 
     def _admit(self, emitted: List[Tuple[int, int]], spent: int) -> int:
@@ -771,23 +825,21 @@ class Scheduler:
                 self._begin_chunked(free.pop(0), req, m, pblocks)
                 continue
             plen = req.prompt.shape[0]
-            if m:
-                tlen = plen - m
-                bucket = self._bucket_tail(tlen, m)
-                toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
-                toks[0, :tlen] = req.prompt[m:]
-                logits, cache = self._prefill_resume(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([tlen - 1], jnp.int32),
-                    self.pool.read_prefix(pblocks))
-            else:
-                bucket = self._bucket(plen)
-                toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
-                toks[0, :plen] = req.prompt
-                logits, cache = self._prefill(
-                    self.params, jnp.asarray(toks),
-                    jnp.asarray([plen - 1], jnp.int32))
-            first = int(np.asarray(jnp.argmax(logits, -1))[0])
+            tlen = plen - m
+            bucket = self._bucket_tail(tlen, m) if m else self._bucket(plen)
+            toks = np.full((1, bucket), self.scfg.pad_id, np.int32)
+            toks[0, :tlen] = req.prompt[m:]
+            li = jnp.asarray([tlen - 1], jnp.int32)
+            prefix = self.pool.read_prefix(pblocks) if m else None
+            with _span("serve.prefill", lambda: dict(
+                    rid=req.rid, tokens=tlen, bucket=bucket)):
+                if m:
+                    logits, cache = self._prefill_resume(
+                        self.params, jnp.asarray(toks), li, prefix)
+                else:
+                    logits, cache = self._prefill(self.params,
+                                                  jnp.asarray(toks), li)
+                first = int(self._first_tokens(logits)[0])
             now = self.clock()
             self.metrics.on_admit(req.rid, now, prefix_tokens=m)
             self.metrics.on_token(req.rid, now)
@@ -802,11 +854,9 @@ class Scheduler:
                 self.metrics.on_finish(req.rid, now)
                 continue
             slot = free.pop(0)
-            if self._prefix_on:
-                self.pool.admit(slot, cache, plen, n_tok,
-                                prompt=req.prompt, prefix_blocks=pblocks)
-            else:
-                self.pool.admit(slot, cache, plen, n_tok)
+            self._pool_admit(slot, req, cache, plen,
+                             prompt=req.prompt if self._prefix_on else None,
+                             prefix_blocks=pblocks)
             self._slot_rid[slot] = req.rid
             self._active_req[req.rid] = req
             self._tokens[slot, 0] = first
@@ -820,49 +870,65 @@ class Scheduler:
 
         Returns the (rid, token) pairs emitted this step.
         """
-        emitted: List[Tuple[int, int]] = []
-        spent = self._continue_prefills(emitted)
-        self._admit(emitted, spent)
-        active = self.decoding_slots
-        if active.any():
-            if self.pool.paged and self.pool.has_shared:
-                # copy-on-write: each active slot writes its KV at
-                # _pos.._pos+width-1 this step (width > 1 under
-                # speculation: draft and verify both write the whole run)
-                # — upgrade any shared target block to a private copy
-                # first so sibling slots / the prefix index keep their
-                # bits (cheap host check when nothing is shared;
-                # ensure_writable no-ops past the slot's reservation)
-                width = self._spec.k if self._spec is not None else 1
-                for slot in np.flatnonzero(active):
-                    for i in range(width):
-                        self.pool.ensure_writable(int(slot),
-                                                  int(self._pos[slot]) + i)
-            if self._spec is not None:
-                self._spec_step(active, emitted)
-            else:
-                next_tok, _, new_caches = self._decode(
-                    self.params, jnp.asarray(self._tokens),
-                    jnp.asarray(self._pos), jnp.asarray(active),
-                    self.pool.caches, self.pool.block_tables)
-                self.pool.caches = new_caches
-                toks = np.asarray(next_tok)
-                now = self.clock()
-                for slot in np.flatnonzero(active):
-                    rid = int(self._slot_rid[slot])
-                    tok = int(toks[slot, 0])
-                    self._outputs[rid].append(tok)
-                    self.metrics.on_token(rid, now)
-                    emitted.append((rid, tok))
-                    self._tokens[slot, 0] = tok
-                    self._pos[slot] += 1
-                    self._remaining[slot] -= 1
-                    if (self._remaining[slot] <= 0
-                            or tok == self.scfg.eos_id):
-                        self._finish(int(slot), now)
-        self.metrics.sample_queue(len(self.queue), self.n_active)
-        self.metrics.sample_pool(self.pool.stats(), self._tokens_live())
+        self._step_num += 1
+        with StepTraceAnnotation("serve.step", step_num=self._step_num):
+            emitted: List[Tuple[int, int]] = []
+            spent = self._continue_prefills(emitted)
+            with _span("serve.admit"):
+                self._admit(emitted, spent)
+            active = self.decoding_slots
+            if active.any():
+                with _span("serve.decode",
+                           lambda: dict(rows=int(active.sum()))):
+                    self._decode_rows(active, emitted)
+            with _span("serve.sample"):
+                self.metrics.sample_queue(len(self.queue), self.n_active)
+                self.metrics.sample_pool(self.pool.stats(),
+                                         self._tokens_live())
         return emitted
+
+    def _decode_rows(self, active: np.ndarray,
+                     emitted: List[Tuple[int, int]]) -> None:
+        """Advance every decoding slot: one batched decode step, or one
+        speculative round."""
+        if self.pool.paged and self.pool.has_shared:
+            # copy-on-write: each active slot writes its KV at
+            # _pos.._pos+width-1 this step (width > 1 under
+            # speculation: draft and verify both write the whole run)
+            # — upgrade any shared target block to a private copy
+            # first so sibling slots / the prefix index keep their
+            # bits (cheap host check when nothing is shared;
+            # ensure_writable no-ops past the slot's reservation)
+            width = self._spec.k if self._spec is not None else 1
+            for slot in np.flatnonzero(active):
+                for i in range(width):
+                    self.pool.ensure_writable(int(slot),
+                                              int(self._pos[slot]) + i)
+        if self._spec is not None:
+            self._spec_step(active, emitted)
+            return
+        with _span("serve.decode.inputs"):
+            inputs = (jnp.asarray(self._tokens), jnp.asarray(self._pos),
+                      jnp.asarray(active), self.pool.caches,
+                      self.pool.block_tables)
+        with _span("serve.decode.dispatch"):
+            next_tok, _, self.pool.caches = self._decode(self.params,
+                                                         *inputs)
+        with _span("serve.decode.wait"):
+            toks = np.asarray(next_tok)
+        with _span("serve.decode.commit"):
+            now = self.clock()
+            for slot in np.flatnonzero(active):
+                rid = int(self._slot_rid[slot])
+                tok = int(toks[slot, 0])
+                self._outputs[rid].append(tok)
+                self.metrics.on_token(rid, now)
+                emitted.append((rid, tok))
+                self._tokens[slot, 0] = tok
+                self._pos[slot] += 1
+                self._remaining[slot] -= 1
+                if self._remaining[slot] <= 0 or tok == self.scfg.eos_id:
+                    self._finish(int(slot), now)
 
     def _spec_step(self, active: np.ndarray,
                    emitted: List[Tuple[int, int]]) -> None:

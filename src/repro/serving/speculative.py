@@ -103,7 +103,7 @@ class SpeculativeDecoder:
         self.draft_traces = 0
         self.verify_traces = 0
 
-        def _draft_step(p, tokens, pos, active, caches, tables):
+        def serve_draft(p, tokens, pos, active, caches, tables):
             self.draft_traces += 1
             # draft_ctx: trace-time session namespace — the drafting
             # pass's pim_sim callbacks (if any) hit a "draft" session
@@ -112,13 +112,13 @@ class SpeculativeDecoder:
                 return M.decode_step_slots(p, tokens, pos, active, caches,
                                            self.dcfg, block_tables=tables)
 
-        def _verify_step(p, tokens, pos, active, caches, tables):
+        def serve_verify(p, tokens, pos, active, caches, tables):
             self.verify_traces += 1
             return M.decode_run_slots(p, tokens, pos, active, caches,
                                       self.cfg, block_tables=tables)
 
-        self._draft = M.serve_jit(_draft_step)
-        self._verify = M.serve_jit(_verify_step)
+        self._draft = M.serve_jit(serve_draft)
+        self._verify = M.serve_jit(serve_verify)
 
     def run_round(self, params, tokens: np.ndarray, pos: np.ndarray,
                   active: np.ndarray, caches, tables):

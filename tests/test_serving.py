@@ -6,6 +6,8 @@ request mix triggers exactly one jit trace (the recompile counter), and
 at-a-time prefill+decode — continuous batching changes throughput, never
 tokens.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,7 +18,8 @@ from repro.dist import partitioning as dpart
 from repro.launch.mesh import make_host_mesh
 from repro.models import model_lib as M
 from repro.serving import (AdmissionQueue, CachePool, Scheduler,
-                           ServingConfig, make_request, synthetic_requests)
+                           ServingConfig, ServingMetrics, make_request,
+                           synthetic_requests)
 
 B_SLOTS = 3
 
@@ -302,6 +305,81 @@ def test_ttft_tpot_accounting(cfg, params):
     assert s["n_finished"] == 1 and s["total_tokens"] == 3
     assert s["tokens_per_s"] == pytest.approx(3 / 2.0)  # busy window 5..7
     assert s["max_queue_depth"] == 0
+
+
+def _sample_list_summary(queue, pool):
+    """The per-step keys of ``summary()`` as computed from whole sample
+    lists (``queue``: (depth, active) per step; ``pool``: stats dicts with
+    ``tokens_live``) — the arithmetic the running aggregates replace."""
+    mean = ServingMetrics._mean
+    return {
+        "max_queue_depth": max((d for d, _ in queue), default=0),
+        "mean_active_slots": mean([float(a) for _, a in queue]),
+        "peak_kv_bytes": max((p["kv_bytes_in_use"] for p in pool),
+                             default=math.nan),
+        "peak_pool_blocks": max((p["blocks_in_use"] for p in pool),
+                                default=math.nan),
+        "mean_block_occupancy": mean([p["blocks_in_use"] / p["blocks_total"]
+                                      for p in pool if p["blocks_total"]]),
+        "mean_internal_frag": mean(
+            [1.0 - p["tokens_live"] / p["tokens_reserved"] for p in pool
+             if p["tokens_reserved"] and not math.isnan(p["tokens_live"])]),
+        "peak_blocks_shared": max((p.get("blocks_shared", 0.0)
+                                   for p in pool), default=0.0),
+        "cow_copies": max((p.get("cow_copies", 0.0) for p in pool),
+                          default=0.0),
+    }
+
+
+def test_step_aggregates_match_sample_list_arithmetic(cfg, params):
+    """The per-step samples are kept as running aggregates (constant
+    memory); on a recorded sequence ``summary()`` and ``merged()`` give
+    what the whole sample lists gave."""
+    sched = Scheduler(params, cfg, ServingConfig(max_batch=2, prompt_bucket=8,
+                                                 paged=True, block_size=4))
+    queue, pool = [], []
+    m = sched.metrics
+    sample_queue, sample_pool = m.sample_queue, m.sample_pool
+
+    def record_queue(depth, active):
+        queue.append((depth, active))
+        sample_queue(depth, active)
+
+    def record_pool(stats, tokens_live=math.nan):
+        pool.append(dict(stats, tokens_live=tokens_live))
+        sample_pool(stats, tokens_live)
+
+    m.sample_queue, m.sample_pool = record_queue, record_pool
+    for r in synthetic_requests(5, vocab_size=cfg.vocab_size,
+                                prompt_lens=[5, 9, 3], max_new_tokens=4,
+                                seed=3):
+        sched.submit_request(r)
+    sched.run()
+    # edge samples: an empty reservation, nothing live to measure, no
+    # blocks at all, a contiguous pool's stats (no shared-block keys)
+    record_pool(dict(pool[0], tokens_reserved=0.0), 3.0)
+    record_pool(pool[1])
+    record_pool(dict(pool[1], blocks_total=0.0), 1.0)
+    record_pool({"kv_bytes_in_use": 1.0, "kv_bytes_reserved": 1.0,
+                 "blocks_in_use": 2.0, "blocks_total": 2.0,
+                 "tokens_reserved": 8.0, "tokens_in_use": 8.0}, 5.0)
+    assert len(queue) > 3 and len(pool) > 5
+    want = _sample_list_summary(queue, pool)
+    got = m.summary()
+    for k, v in want.items():
+        assert got[k] == pytest.approx(v, nan_ok=True), k
+
+    parts = [ServingMetrics(), ServingMetrics(), ServingMetrics()]
+    for i, (d, a) in enumerate(queue):
+        parts[i % 2].sample_queue(d, a)
+    for i, p in enumerate(pool):
+        parts[i % 2].sample_pool(p, p["tokens_live"])
+    got = ServingMetrics.merged(parts).summary()   # parts[2] is empty
+    for k, v in want.items():
+        assert got[k] == pytest.approx(v, nan_ok=True), k
+    empty = ServingMetrics().summary()
+    for k, v in _sample_list_summary([], []).items():
+        assert empty[k] == pytest.approx(v, nan_ok=True), k
 
 
 # --------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
 __all__ = ["use_mesh", "current_mesh", "mesh_axes", "dp_axes", "tp_axis",
-           "shard", "shard_batch_dim"]
+           "head_axis", "shard", "pin", "shard_batch_dim"]
 
 TP_AXIS = "model"
 
@@ -101,6 +101,21 @@ def tp_axis() -> Optional[str]:
     return _stack.frames[-1][2] if _stack.frames else None
 
 
+def head_axis(heads: int, mesh=None) -> Optional[str]:
+    """The tensor-parallel axis of ``mesh`` (default: the active one) where
+    it splits ``heads`` into whole heads per shard, else None.
+
+    A paged KV pool stores each token as one ``heads * hd`` row; "model"
+    may split the row only between heads, so the decode attention stays
+    local to a shard.  Fewer heads than ranks (MQA) leaves it replicated.
+    """
+    _, tp = mesh_axes(mesh)
+    if tp is None:
+        return None
+    size = (mesh if mesh is not None else current_mesh()).shape[tp]
+    return tp if size > 1 and heads % size == 0 else None
+
+
 def _normalize_entry(mesh, dim_size: int, entry: AxisEntry):
     """Drop axes that are absent or do not divide ``dim_size``."""
     if entry is None:
@@ -140,6 +155,19 @@ def shard(x, *entries: AxisEntry):
     if all(e is None for e in spec):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def pin(x, *entries: AxisEntry):
+    """``shard`` that also imposes a placement replicated on every dim.
+
+    Sharding propagation may otherwise give a loop carry or an output
+    another placement than the argument it came from, and a donated
+    buffer can then not be updated in place."""
+    mesh = current_mesh()
+    if mesh is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, pspec_for(mesh, x.shape, *entries)))
 
 
 def shard_batch_dim(x, dim: int = 0):

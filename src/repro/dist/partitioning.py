@@ -130,7 +130,8 @@ def batch_pspecs(batch, mesh):
         batch, is_leaf=_is_shape_leaf)
 
 
-def cache_pspecs(caches, mesh, *, batch_over_dp: bool = True):
+def cache_pspecs(caches, mesh, *, batch_over_dp: bool = True,
+                 row_heads: Optional[Dict[str, int]] = None):
     """Decode caches ``(n_super, batch, ...)``: batch dim over DP axes, the
     head dim (-2) of rank>=4 leaves over the "model" axis.
 
@@ -141,12 +142,18 @@ def cache_pspecs(caches, mesh, *, batch_over_dp: bool = True):
     cross-device traffic.
 
     Block-paged pools (``serving.PagedCachePool``) reuse the same factory:
-    their attention leaves are ``(n_super, num_blocks, block, heads, hd)``,
-    so dim 1 is the *block* dim — it must stay replicated for the same
-    reason slots do (any slot touches any block), hence paged pools always
-    pass ``batch_over_dp=False``; heads still shard over "model".  The
-    block *table* itself is a tiny replicated int32 array and never gets a
-    spec here.
+    their attention leaves are ``(n_super, num_blocks, block, ...)``, so
+    dim 1 is the *block* dim — it must stay replicated for the same reason
+    slots do (any slot touches any block), hence paged pools always pass
+    ``batch_over_dp=False``.  Their ``k``/``v`` hold each token as one
+    ``heads * hd`` row with the heads major (``model.fold_kv_rows``);
+    ``row_heads`` names such leaves and their head count.  "model" splits
+    a row only where it divides the heads, so each shard holds whole
+    heads and the decode attention stays local to it
+    (``attention.decode_attention_rows``); an MQA/GQA pool with fewer
+    heads than ranks stays replicated, as a ``(heads, hd)`` leaf whose
+    heads do not divide does.  The block *table* itself is a tiny
+    replicated int32 array and never gets a spec here.
 
     Quantized-KV *scale* leaves (``k_scale``/``v_scale``) are the KV leaf
     minus its trailing head-dim axis — ``(n_super, batch, cap, heads)`` —
@@ -162,8 +169,10 @@ def cache_pspecs(caches, mesh, *, batch_over_dp: bool = True):
         entries = [None] * nd
         if nd >= 2 and batch_over_dp:
             entries[1] = dp
-        if nd >= 4 and tp_ax:
-            name = str(getattr(path[-1], "key", path[-1])) if path else ""
+        name = str(getattr(path[-1], "key", path[-1])) if path else ""
+        if name in (row_heads or {}):
+            entries[-1] = dctx.head_axis(row_heads[name], mesh)
+        elif nd >= 4 and tp_ax:
             entries[-1 if name.endswith("_scale") else -2] = tp_ax
         return dctx.pspec_for(mesh, s.shape, *entries)
 

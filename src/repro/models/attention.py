@@ -223,15 +223,83 @@ def decode_attention(q, k_cache, v_cache, cache_len=None, *,
         mask = pos[None, :] < cache_len  # (B?, S) — cache_len scalar or (B,)
         if window is not None:
             mask = mask & (pos[None, :] > cache_len - 1 - window)
+    p = _decode_probs(scores, mask, v_cache.dtype)
+    out = jnp.einsum("bhgqs,bshd->bqhgd", p, v_cache,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, h, d).astype(q.dtype)
+
+
+def _decode_probs(scores, mask, dtype):
+    """Softmax of decode scores (B, Hkv, G, Sq, S) over the keys ``mask``
+    (B, S) or (B, Sq, S) lets through, in ``dtype``."""
+    b, s = scores.shape[0], scores.shape[-1]
     if mask.ndim == 3:          # per-query validity (B, Sq, S)
         mask5 = mask[:, None, None, :, :]
     else:
         mask5 = jnp.broadcast_to(mask, (b, s))[:, None, None, None, :]
     scores = jnp.where(mask5, scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bhgqs,bshd->bqhgd", p, v_cache,
+    return jax.nn.softmax(scores, axis=-1).astype(dtype)
+
+
+def decode_attention_rows(q, k_rows, v_rows, valid) -> jnp.ndarray:
+    """``decode_attention`` over a cache that holds each position as one
+    row of ``Hkv * D`` values: q (B, Sq, H, D), k_rows / v_rows (B, S,
+    Hkv * D), ``valid`` (B, S) or per query (B, Sq, S).
+
+    The paged pool keeps its K/V rows whole (``model.fold_kv_rows``), and
+    splitting the gathered rows into (Hkv, D) for per-head contractions
+    makes a TPU lay the whole gathered cache out again, as D (64, 80) is
+    narrower than its 128 lanes.  Here each query head contracts the whole
+    row, its entries outside the head's own KV head zero, and each head
+    keeps its own D columns of the output row: the same sums over the same
+    products, at the cost of multiplications by zero, which are cheap
+    next to reading the cache.  Only the order in which the accumulation
+    adds a score's D products may differ from ``decode_attention``'s.
+    A batch of one runs as two rows, as there.
+
+    Under a "model" axis that splits the KV heads, the rows arrive split
+    between whole heads (``dist.cache_pspecs``), and each shard attends
+    with its own query heads over its own part of every row: a score sums
+    over its head's D entries alone, so contracting a whole sharded row
+    would add partial scores across chips."""
+    ax = dctx.head_axis(k_rows.shape[-1] // q.shape[-1])
+    if ax is not None:
+        mesh, dp = dctx.current_mesh(), dctx.dp_axes()
+        heads = dctx.pspec_for(mesh, q.shape, dp, None, ax)
+        rows = dctx.pspec_for(mesh, k_rows.shape, dp, None, ax)
+        return jax.shard_map(
+            _attend_rows, mesh=mesh,
+            in_specs=(heads, rows, rows, dctx.pspec_for(mesh, valid.shape,
+                                                        dp)),
+            out_specs=heads, check_vma=False)(q, k_rows, v_rows, valid)
+    return _attend_rows(q, k_rows, v_rows, valid)
+
+
+def _attend_rows(q, k_rows, v_rows, valid):
+    """``decode_attention_rows`` over the heads and rows it is given."""
+    if q.shape[0] == 1:
+        def dup(a):
+            return jnp.concatenate([a, a])
+
+        return _attend_rows(dup(q), dup(k_rows), dup(v_rows),
+                            dup(valid))[:1]
+    b, sq, h, d = q.shape
+    hkv = k_rows.shape[-1] // d
+    n_rep = h // hkv
+    # own[g, 0, k, 0]: query group g reads KV head k
+    own = (jnp.arange(hkv)[:, None] == jnp.arange(hkv)[None, :]
+           )[:, None, :, None]
+    q_rows = jnp.where(own, q.reshape(b, sq, hkv, n_rep, 1, d), 0)
+    q_rows = q_rows.reshape(b, sq, hkv, n_rep, hkv * d)
+    scores = jnp.einsum("bqhgc,bsc->bhgqs", q_rows, k_rows,
+                        preferred_element_type=jnp.float32)
+    scores = scores / math.sqrt(d)
+    p = _decode_probs(scores, valid, v_rows.dtype)
+    out = jnp.einsum("bhgqs,bsc->bhgqc", p, v_rows,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    out = out.reshape(b, hkv, n_rep, sq, hkv, d)
+    out = jnp.sum(jnp.where(own[:, :, None], out, 0), axis=4)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d).astype(q.dtype)
 
 
 def cross_attention(q, k, v) -> jnp.ndarray:

@@ -205,11 +205,20 @@ def _quantize_kv(x):
 
 
 def _dequantize_kv(q, scale, dtype):
-    return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
+    """int8 K/V and their per-(token, head) scales -> ``dtype``.  ``q`` is
+    (..., hkv, hd) with ``scale`` (..., hkv), or (..., hkv * hd) rows as
+    the paged pool stores them (``fold_kv_rows``), where a row's hd values
+    of one head share that head's scale."""
+    if q.ndim == scale.ndim:
+        scale = jnp.repeat(scale, q.shape[-1] // scale.shape[-1], axis=-1)
+    else:
+        scale = scale[..., None]
+    return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
 def _self_attention(x, p, cfg: ModelConfig, positions, mode, cache, pos,
-                    causal=True, block_tables=None, segment_ids=None):
+                    causal=True, block_tables=None, segment_ids=None,
+                    layer=None):
     b, s, d = x.shape
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     quant = cfg.kv_cache_dtype == "int8"
@@ -222,77 +231,51 @@ def _self_attention(x, p, cfg: ModelConfig, positions, mode, cache, pos,
         k = apply_rope(k, positions, cfg.rope_theta)
     new_cache = None
     if mode == "decode" and block_tables is not None:
-        # Block-paged cache: leaves are (num_blocks, block, hkv, hd) physical
-        # stores shared by every slot; ``block_tables`` (B, blocks_per_slot)
-        # maps each slot's logical blocks to physical ones (sentinel entries
-        # point at the reserved trash block 0, which no reader unmasks).
-        # Logical index: the absolute position, or — under a sliding window —
-        # the position modulo the block-rounded ring capacity.
-        bs_blk = cache["k"].shape[1]
+        # Block-paged cache: ``cache`` holds the *whole* paged pool, every
+        # layer's (n_super, num_blocks, block, ...) physical store shared
+        # by every slot (``paged_cache_specs``), and ``layer`` picks this
+        # layer's part; ``block_tables`` (B, blocks_per_slot) maps each
+        # slot's logical blocks to physical ones (sentinel entries point at
+        # the reserved trash block 0, which no reader unmasks).  Logical
+        # index: the absolute position, or — under a sliding window — the
+        # position modulo the block-rounded ring capacity.  The step's rows
+        # are written into the pool in place (the pool rides the layer
+        # scan's carry, see ``_decoder_stack``), then the layer gathers
+        # its blocks back, and the whole pool is returned.
+        bs_blk = cache["k"].shape[2]
         lcap = block_tables.shape[1] * bs_blk
         r = jnp.arange(lcap)
+        widx, blk, off = paged_write_targets(cfg, pos, s, block_tables,
+                                             bs_blk)
         if s > 1:
-            # Speculative verify run: token i of the run is written at
-            # logical row pos+i, and query i's mask stops at its own row —
-            # (B, s, lcap) per-query validity.  Rows past the slot's block
-            # reservation hit sentinel table entries (trash block); rows at
-            # or past lcap itself are routed to the trash block explicitly,
-            # because the clamped gather would otherwise corrupt the slot's
-            # last real block.  Sliding-window rings are rejected here:
-            # rolling back a rejected draft would need ring rows the run's
-            # own writes already destroyed.
-            if cfg.sliding_window:
-                raise ValueError("multi-position decode (speculative verify)"
-                                 " does not support sliding_window")
-            widx = pos[:, None] + jnp.arange(s)            # (B, s)
-            valid = r[None, None, :] <= widx[:, :, None]   # (B, s, lcap)
-            blk = block_tables[jnp.arange(b)[:, None],
-                               jnp.minimum(widx, lcap - 1) // bs_blk]
-            blk = jnp.where(widx < lcap, blk, 0)
+            # Speculative verify run: query i's mask stops at its own row
+            # pos+i — (B, s, lcap) per-query validity.
+            valid = r[None, None, :] <= widx[:, :, None]
         elif cfg.sliding_window:
             ring = cfg.window_ring_blocks(bs_blk) * bs_blk
-            widx = pos % ring
             _, in_ring = ring_slot_positions(pos[:, None], r[None, :],
                                              ring, cfg.sliding_window)
             valid = (r[None, :] < ring) & in_ring
-            blk = block_tables[jnp.arange(b), widx // bs_blk]
         else:
-            # Same out-of-capacity guard as the s > 1 run above: the
-            # speculative draft pass drives this single-token path up to
-            # draft_k - 2 rows past a slot's last reserved position, where
-            # the clamped table gather would resolve to the slot's *last
-            # real block* and overwrite a committed row with draft-mode
-            # bits the verify step never rewrites.  Route those writes to
-            # the trash block instead.
-            widx = pos
             valid = r[None, :] <= pos[:, None]
-            blk = block_tables[jnp.arange(b),
-                               jnp.minimum(widx, lcap - 1) // bs_blk]
-            blk = jnp.where(widx < lcap, blk, 0)
-        off = widx % bs_blk
-
-        def put(c, new):
-            new = new if s > 1 else new[:, 0]
-            return c.at[blk, off].set(new.astype(c.dtype))
-
-        def gather(c):
-            return c[block_tables].reshape((b, lcap) + c.shape[2:])
 
         if quant:
             kq, ks = _quantize_kv(k)
             vq, vs = _quantize_kv(v)
-            kc, vc = put(cache["k"], kq), put(cache["v"], vq)
-            ksc, vsc = put(cache["k_scale"], ks), put(cache["v_scale"], vs)
-            k_full = _dequantize_kv(gather(kc), gather(ksc),
-                                    cfg.compute_dtype)
-            v_full = _dequantize_kv(gather(vc), gather(vsc),
-                                    cfg.compute_dtype)
-            new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
+            rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
         else:
-            kc, vc = put(cache["k"], k), put(cache["v"], v)
-            k_full, v_full = gather(kc), gather(vc)
-            new_cache = {"k": kc, "v": vc}
-        out = attn.decode_attention(q, k_full, v_full, valid=valid)
+            rows = {"k": k, "v": v}
+        new_cache, views = {}, {}
+        heads_ax = dctx.head_axis(hkv)
+        for key, new in rows.items():
+            new = fold_kv_rows(key, new.astype(cache[key].dtype))
+            new_cache[key], views[key] = paged_append(
+                cache[key], layer, new, blk, off, block_tables, heads_ax)
+        if quant:
+            for key in ("k", "v"):
+                views[key] = _dequantize_kv(views[key], views[key + "_scale"],
+                                            cfg.compute_dtype)
+        out = attn.decode_attention_rows(q, views["k"], views["v"], valid)
     elif mode == "decode":
         cap = cache["k"].shape[1]
         per_slot = jnp.ndim(pos) == 1  # continuous batching: (B,) positions
@@ -518,14 +501,16 @@ def _xlstm(x, p, cfg: ModelConfig, mode, cache, kind):
 
 def apply_block(kind: str, x, p, cfg: ModelConfig, *, positions, mode,
                 cache=None, pos=None, memory=None, block_tables=None,
-                segment_ids=None):
-    """Returns (x, cache_out or None)."""
+                segment_ids=None, layer=None):
+    """Returns (x, cache_out or None).  In a paged decode step ``cache``
+    holds the whole paged pool and ``layer`` this super-block's index
+    into it (see ``_self_attention``)."""
     out_cache = {}
     if kind in ("ad", "ae", "ar", "adx", "enc"):
         x, c = _self_attention(x, p, cfg, positions, mode, cache, pos,
                                causal=(kind != "enc"),
                                block_tables=block_tables,
-                               segment_ids=segment_ids)
+                               segment_ids=segment_ids, layer=layer)
         if c:
             out_cache.update(c)
     if kind == "adx":
@@ -562,41 +547,65 @@ def _decoder_stack(params, x, cfg: ModelConfig, *, positions, mode,
                    segment_ids=None):
     """Scan over super-blocks. caches: dict pos->stacked cache (or None).
     ``block_tables`` is shared by every layer (one slot->physical-block map
-    for the whole paged pool), so it rides the closure, not the scan."""
+    for the whole paged pool), so it rides the closure, not the scan.
 
-    def body(xc, layer_inputs):
-        x = xc
+    A paged decode step's pool (its ``PAGED_KV_KEYS`` leaves) rides the
+    scan's carry whole: each layer writes the step's rows into it in
+    place, at its own layer index, and reads its blocks back from it.
+    Scanning the pool as ``xs``/``ys`` instead slices each layer out and
+    writes it back whole, which costs a copy of the pool every step even
+    when the caller donates it.  Every other leaf (recurrent state, the
+    contiguous pool) rides ``xs``/``ys``."""
+    pools = {}
+    if mode == "decode" and block_tables is not None and caches:
+        pools = {i: {key: leaf for key, leaf in c.items()
+                     if key in PAGED_KV_KEYS} for i, c in caches.items()}
+        pools = {i: c for i, c in pools.items() if c}
+        caches = {i: {key: leaf for key, leaf in c.items()
+                      if key not in PAGED_KV_KEYS} for i, c in caches.items()}
+
+    def body(carry, layer_inputs):
+        x, pools = carry
+        pools = dict(pools)
+        pslices, cslices, layer = layer_inputs
         new_caches = {}
         for i, kind in enumerate(cfg.pattern):
-            pslice = layer_inputs[0][str(i)]
-            cslice = layer_inputs[1].get(str(i)) if layer_inputs[1] else None
-            x, c = apply_block(kind, x, pslice, cfg, positions=positions,
-                               mode=mode, cache=cslice, pos=pos, memory=memory,
+            cslice = cslices.get(str(i)) if cslices else None
+            if str(i) in pools:
+                cslice = {**(cslice or {}), **pools[str(i)]}
+            x, c = apply_block(kind, x, pslices[str(i)], cfg,
+                               positions=positions, mode=mode, cache=cslice,
+                               pos=pos, memory=memory,
                                block_tables=block_tables,
-                               segment_ids=segment_ids)
+                               segment_ids=segment_ids, layer=layer)
             if c is not None:
+                if str(i) in pools:
+                    pools[str(i)] = {key: c.pop(key) for key in pools[str(i)]}
                 new_caches[str(i)] = c
-        return x, (new_caches or None)
+        return (x, pools), (new_caches or None)
 
     if cfg.remat and mode == "train":
         body = jax.checkpoint(body, prevent_cse=False)
 
     xs = (params["blocks"], caches if caches is not None
-          else {str(i): None for i in range(len(cfg.pattern))})
+          else {str(i): None for i in range(len(cfg.pattern))},
+          jnp.arange(cfg.n_super))
     if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, xs)
-        return x, new_caches
-    # unrolled (dry-run mode: XLA cost analysis counts while-loop bodies once,
-    # so roofline cells lower with the stack unrolled)
-    per_super = []
-    for i in range(cfg.n_super):
-        sl = jax.tree.map(lambda a: a[i], xs)
-        x, c = body(x, sl)
-        per_super.append(c)
-    if any(c is not None for c in per_super):
-        new_caches = jax.tree.map(lambda *ls: jnp.stack(ls), *per_super)
+        (x, pools), new_caches = jax.lax.scan(body, (x, pools), xs)
     else:
-        new_caches = None
+        # unrolled (dry-run mode: XLA cost analysis counts while-loop
+        # bodies once, so roofline cells lower with the stack unrolled)
+        per_super = []
+        for i in range(cfg.n_super):
+            sl = jax.tree.map(lambda a: a[i], xs)
+            (x, pools), c = body((x, pools), sl)
+            per_super.append(c)
+        if any(c is not None for c in per_super):
+            new_caches = jax.tree.map(lambda *ls: jnp.stack(ls), *per_super)
+        else:
+            new_caches = None
+    for i, c in pools.items():
+        new_caches[i].update(c)
     return x, new_caches
 
 
@@ -648,15 +657,21 @@ def _vocab_logits(logits, cfg):
     return logits[..., :cfg.vocab_size].astype(jnp.float32)
 
 
-def serve_jit(fn):
+def serve_jit(fn, donate=()):
     """``jax.jit`` for the serving entry points (prefill, decode, verify).
 
     Excess precision is off: with it on, XLA may drop a bf16 rounding
     between fused ops, and whether it does depends on the batch's shape —
     on a TPU v5e a request's ``quant`` logits differed between a batch of
     one and a batch of four.  Rounding after every op keeps each row's
-    result its own, whatever else shares the step."""
-    return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False})
+    result its own, whatever else shares the step.
+
+    ``donate`` names arguments whose buffers the program may reuse for its
+    outputs (``jax.jit``'s ``donate_argnames``): the decode steps donate
+    the KV pool, which they return updated, so its rows are written in
+    place; the caller must not touch the arrays it passed afterwards."""
+    return jax.jit(fn, donate_argnames=donate,
+                   compiler_options={"xla_allow_excess_precision": False})
 
 
 def _pim_ctx(cfg: ModelConfig):
@@ -913,6 +928,85 @@ def decode_run_slots(params, tokens, pos, active, caches, cfg: ModelConfig,
 #: cache tree — recurrent state (ssm/conv/c/n/m) and cross-attention
 #: memory (xk/xv) — is fixed-size per slot and stays slot-indexed.
 PAGED_KV_KEYS = ("k", "v", "k_scale", "v_scale")
+#: The paged leaves stored as (hkv * hd) rows (``fold_kv_rows``).
+FOLDED_KV_KEYS = ("k", "v")
+
+
+def fold_kv_rows(key: str, x):
+    """A K/V leaf's entries as the paged pool stores them: ``k`` and ``v``
+    fold their trailing (hkv, hd) into one row of hkv * hd values, scales
+    keep their (hkv,).  A TPU lays an array whose last dim is narrower
+    than its 128 lanes out with another dim minor — for a (..., block,
+    hkv, 64) pool, the block dim — and neither the decode step's row
+    writes nor its block gathers run in place on that layout: each step
+    then copied the pool to another layout and back.  A row of hkv * hd
+    (1024 for qwen1.5-0.5b, 640 for h2o-danube-1.8b) keeps the pool in
+    the plain row-major layout, and the decode step's attention reads the
+    rows as they are (``attention.decode_attention_rows``)."""
+    return x.reshape(x.shape[:-2] + (-1,)) if key in FOLDED_KV_KEYS else x
+
+
+def unfold_kv_rows(key: str, x, cfg: ModelConfig):
+    """Inverse of ``fold_kv_rows``: (..., hkv * hd) rows -> (..., hkv, hd)."""
+    if key in FOLDED_KV_KEYS:
+        return x.reshape(x.shape[:-1] + (cfg.n_kv_heads, cfg.hd))
+    return x
+
+
+def paged_append(pool, layer, rows, blk, off, block_tables, heads_ax=None):
+    """Write one layer's new rows into a paged pool leaf, then read that
+    layer's blocks of every slot back: ``(pool, view)``.
+
+    ``pool`` (n_super, num_blocks, block, ...); ``rows`` (B, s, ...) land
+    at the physical blocks ``blk`` and offsets ``off`` (B, s) of
+    ``paged_write_targets``, at the layer index ``layer``; ``view`` is
+    (B, lcap, ...) in each slot's logical order.  Both index the whole
+    pool, so no layer-sized slice of it is made, and the write is in place
+    where the pool is a loop carry of a jit that donates it.  Under a mesh
+    the rows are placed as ``dist.cache_pspecs`` placed the pool, their
+    last dim over ``heads_ax`` (``dist.context.head_axis``): rows split
+    otherwise (an MQA projection's over "model") would pull the pool to
+    their placement, and the donated buffer could not hold the result."""
+    rows = dctx.pin(rows, *([None] * (rows.ndim - 1)), heads_ax)
+    pool = pool.at[layer, blk, off].set(rows)
+    b = block_tables.shape[0]
+    lcap = block_tables.shape[1] * pool.shape[2]
+    view = pool[layer, block_tables].reshape((b, lcap) + pool.shape[3:])
+    return pool, view
+
+
+def paged_write_targets(cfg: ModelConfig, pos, s: int, block_tables,
+                        block_size: int):
+    """Where a paged decode step writes its ``s`` new K/V rows per slot.
+
+    Returns ``(widx, blk, off)``, each (B, s) int32: the logical row
+    (``pos + i``, or under a sliding window the position modulo the
+    block-rounded ring), and the physical block and offset it lands at.
+    A row at or past the slot's logical capacity goes to the trash block
+    0: the speculative draft pass drives single-token steps up to
+    ``draft_k - 2`` rows past a slot's last reserved position, where the
+    clamped table lookup would resolve to the slot's *last real block*
+    and overwrite a committed row the verify step never rewrites.  Rows
+    past the slot's reservation but inside ``lcap`` hit sentinel table
+    entries, which point at the trash block too.
+    """
+    b = block_tables.shape[0]
+    lcap = block_tables.shape[1] * block_size
+    slots = jnp.arange(b)[:, None]
+    if s > 1 and cfg.sliding_window:
+        # rolling back a rejected draft would need ring rows the run's own
+        # writes already destroyed
+        raise ValueError("multi-position decode (speculative verify)"
+                         " does not support sliding_window")
+    if cfg.sliding_window:
+        ring = cfg.window_ring_blocks(block_size) * block_size
+        widx = (pos % ring)[:, None]
+        blk = block_tables[slots, widx // block_size]
+    else:
+        widx = pos[:, None] + jnp.arange(s)
+        blk = block_tables[slots, jnp.minimum(widx, lcap - 1) // block_size]
+        blk = jnp.where(widx < lcap, blk, 0)
+    return widx, blk, widx % block_size
 
 
 def ring_slot_positions(last_pos, r, ring: int, window: int):
@@ -937,8 +1031,9 @@ def paged_cache_specs(cfg: ModelConfig, batch: int, seq_len: int,
     Each ``PAGED_KV_KEYS`` leaf becomes a ``(n_super, num_blocks,
     block_size, ...)`` physical store shared by every slot (block 0 is the
     pool's reserved sentinel/trash block); the per-slot token capacity
-    moves into the block table, not the array shapes.  Non-attention
-    leaves keep their ``(n_super, batch, ...)`` slot layout.
+    moves into the block table, not the array shapes.  ``k`` and ``v``
+    store each token as one (hkv * hd) row (``fold_kv_rows``).
+    Non-attention leaves keep their ``(n_super, batch, ...)`` slot layout.
     """
     out = cache_specs(cfg, batch, seq_len)
     for c in out.values():
@@ -946,9 +1041,11 @@ def paged_cache_specs(cfg: ModelConfig, batch: int, seq_len: int,
             if key in c:
                 s = c[key]
                 # (ns, batch, cap, ...) -> (ns, num_blocks, block, ...)
+                row = s.shape[3:]
+                if key in FOLDED_KV_KEYS:
+                    row = (row[0] * row[1],)
                 c[key] = jax.ShapeDtypeStruct(
-                    (s.shape[0], num_blocks, block_size) + s.shape[3:],
-                    s.dtype)
+                    (s.shape[0], num_blocks, block_size) + row, s.dtype)
     return out
 
 

@@ -13,15 +13,17 @@ writes on the batch dim.
 the paper divides one fixed crossbar into dynamic partitions so
 independent work shares the substrate without worst-case reservation, the
 paged pool divides each attention-KV leaf into a ``(num_blocks,
-block_size, ...)`` physical store shared by all slots.  A per-slot block
-table (``(max_batch, blocks_per_slot)`` int32, sentinel ``0`` pointing at
-a reserved trash block) maps logical token blocks to physical ones; a
-host-side free-list allocator reserves exactly
+block_size, ...)`` physical store shared by all slots (``k``/``v`` hold
+each token as one ``hkv * hd`` row: see ``models.model.fold_kv_rows``;
+``read_slot`` and ``read_prefix`` give them back as ``(hkv, hd)``).  A
+per-slot block table (``(max_batch, blocks_per_slot)`` int32, sentinel
+``0`` pointing at a reserved trash block) maps logical token blocks to
+physical ones; a host-side free-list allocator reserves exactly
 ``ceil((prompt + budget) / block_size)`` blocks per request at admit time
 (admission defers when the free list is short — never a mid-decode OOM),
 and evict returns the blocks.  The jitted decode step reads through a
 gather on the block table, whose shape is fixed, so block churn never
-recompiles anything.
+recompiles anything, and writes its new rows into the pool in place.
 
 Paging is also what unblocks **sliding-window serving**: a windowed slot
 is a *ring* over its block list with capacity ``ceil(window / block) *
@@ -69,7 +71,9 @@ reservation; the physical/logical gap is exactly the prefix-sharing win,
 reported via ``blocks_shared``.
 
 Under a mesh both pools are placed by ``dist.cache_pspecs(...,
-batch_over_dp=False)``: heads shard over "model", but the slot dim — and
+batch_over_dp=False)``: heads shard over "model" (a paged pool's rows
+only where "model" divides the KV heads, else they replicate), but the
+slot dim — and
 for paged leaves the *block* dim in its place — stays replicated:
 continuous batching touches arbitrary slots/blocks every step, and a
 sharded dim 1 would make each admit a cross-device scatter.  Block tables
@@ -372,9 +376,10 @@ class PagedCachePool:
             lambda s: jnp.zeros(s.shape, s.dtype), specs,
             is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
         if mesh is not None:
+            rows = {key: cfg.n_kv_heads for key in M.FOLDED_KV_KEYS}
             self.caches = jax.device_put(self.caches, dpart.tree_shardings(
-                dpart.cache_pspecs(self.caches, mesh, batch_over_dp=False),
-                mesh))
+                dpart.cache_pspecs(self.caches, mesh, batch_over_dp=False,
+                                   row_heads=rows), mesh))
 
         # host allocator state: free-list (LIFO keeps reuse warm), per-slot
         # block lists, per-block refcounts, and the sentinel-padded table
@@ -419,7 +424,7 @@ class PagedCachePool:
                 logical = jnp.where(valid.reshape(vshape), logical, 0)
                 blocks = logical.reshape(
                     (logical.shape[0], self.blocks_per_slot, bs)
-                    + logical.shape[2:]).astype(c.dtype)
+                    + c.shape[3:]).astype(c.dtype)
                 # sentinel-padded rows scatter their tail into trash block 0
                 return c.at[:, table_row].set(blocks)
 
@@ -455,7 +460,7 @@ class PagedCachePool:
                 logical = jnp.where(valid.reshape(vshape), logical, 0)
                 blocks = logical.reshape(
                     (logical.shape[0], self.blocks_per_slot, bs)
-                    + logical.shape[2:]).astype(c.dtype)
+                    + c.shape[3:]).astype(c.dtype)
                 return c.at[:, table_row].set(blocks)
 
             out = {}
@@ -495,8 +500,9 @@ class PagedCachePool:
                 for key, leaf in c.items():
                     if key in M.PAGED_KV_KEYS:
                         g = leaf[:, table_row]          # (ns, bps, bs, ...)
-                        oc[key] = g.reshape((g.shape[0], 1, lcap)
-                                            + g.shape[3:])
+                        oc[key] = M.unfold_kv_rows(
+                            key, g.reshape((g.shape[0], 1, lcap)
+                                           + g.shape[3:]), cfg)
                     else:
                         oc[key] = jax.lax.dynamic_slice_in_dim(
                             leaf, slot, 1, axis=1)
@@ -514,8 +520,9 @@ class PagedCachePool:
                 for key, leaf in c.items():
                     if key in M.PAGED_KV_KEYS:
                         g = leaf[:, blocks]             # (ns, nb, bs, ...)
-                        oc[key] = g.reshape((g.shape[0], 1, nb * bs)
-                                            + g.shape[3:])
+                        oc[key] = M.unfold_kv_rows(
+                            key, g.reshape((g.shape[0], 1, nb * bs)
+                                           + g.shape[3:]), cfg)
                 if oc:
                     out[li] = oc
             return out

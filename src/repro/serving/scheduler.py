@@ -362,6 +362,13 @@ class Scheduler:
         self._plain_decode_traces = 0   # retraces of the plain (B, 1) jit
         self._step_num = 0              # ``serve.step`` spans so far
 
+        # the decode steps donate a paged pool, which they return updated,
+        # so a step writes its new K/V rows in place (``self.pool.caches``
+        # is replaced by the result before anything reads it again).  The
+        # contiguous pool rides the layer scan whole, where donating adds
+        # copies of it instead of removing them.
+        self._donate = ("caches",) if self.pool.paged else ()
+
         # speculative decode: active only when a round can beat plain
         # decode — draft_k=1 drafts nothing (the verify step *is* plain
         # decode) and a draft mode equal to the verify mode would run the
@@ -373,7 +380,8 @@ class Scheduler:
             from repro.serving.speculative import SpeculativeDecoder
 
             self._spec = SpeculativeDecoder(cfg, scfg.draft_mode,
-                                            scfg.draft_k)
+                                            scfg.draft_k,
+                                            donate=self._donate)
 
         # each program is named after its function (``jit_serve_decode``
         # in a profile), so a trace gives device time to it by name
@@ -398,7 +406,7 @@ class Scheduler:
         def serve_prefill_packed(p, toks, pos, seg, li):
             return M.prefill_packed(p, toks, pos, seg, li, cfg)
 
-        self._decode = M.serve_jit(serve_decode)
+        self._decode = M.serve_jit(serve_decode, donate=self._donate)
         self._prefill = M.serve_jit(serve_prefill)
         self._prefill_resume = M.serve_jit(serve_prefill_resume)
         self._prefill_packed = M.serve_jit(serve_prefill_packed)

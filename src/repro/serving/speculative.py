@@ -89,9 +89,13 @@ class SpeculativeDecoder:
     verify *should* share the engine's per-row integer quantization
     ("quant"/"quant_tp" drafting for a "pim_sim" or "quant_tp" verify)
     so acceptance stays ~100%; any pairing is still exact, just slower.
+    ``donate`` names the arguments both jits donate, as the scheduler's
+    plain decode step does (``("caches",)`` for a paged pool): the
+    ``caches`` passed to ``run_round`` are then consumed.
     """
 
-    def __init__(self, cfg: ModelConfig, draft_mode: str, draft_k: int):
+    def __init__(self, cfg: ModelConfig, draft_mode: str, draft_k: int,
+                 donate=()):
         if draft_k < 2:
             raise ValueError("SpeculativeDecoder needs draft_k >= 2 "
                              "(draft_k=1 is plain decode; the scheduler "
@@ -117,8 +121,8 @@ class SpeculativeDecoder:
             return M.decode_run_slots(p, tokens, pos, active, caches,
                                       self.cfg, block_tables=tables)
 
-        self._draft = M.serve_jit(serve_draft)
-        self._verify = M.serve_jit(serve_verify)
+        self._draft = M.serve_jit(serve_draft, donate=donate)
+        self._verify = M.serve_jit(serve_verify, donate=donate)
 
     def run_round(self, params, tokens: np.ndarray, pos: np.ndarray,
                   active: np.ndarray, caches, tables):

@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 
 from repro.dist import context as dctx
-from repro.dist import partitioning as dpart
 from repro.launch.mesh import make_host_mesh
 from repro.models import model_lib as M
 from repro.serving import PagedCachePool, Scheduler, ServingConfig
@@ -183,23 +182,21 @@ def test_paged_specs_and_block_math(cfg, wcfg):
 
 def test_paged_pool_under_mesh_matches_meshless(cfg, params):
     """Block-table round-trip under the 8-device mesh: paged leaves keep
-    the block dim replicated with heads on "model" (cache_pspecs), the
-    table replicates, and generations match the meshless run."""
+    the block dim replicated with heads on "model" (cache_pspecs: the
+    heads are the major part of each (hkv * hd) row), the table
+    replicates, and generations match the meshless run."""
     mesh = make_host_mesh(model=2)
     with dctx.use_mesh(mesh):
         sched = Scheduler(params, cfg,
                           ServingConfig(max_batch=2, prompt_bucket=8,
                                         paged=True, block_size=8),
                           mesh=mesh)
-        specs = dpart.cache_pspecs(sched.pool.caches, mesh,
-                                   batch_over_dp=False)
-        for c in specs.values():
-            for key, spec in c.items():
-                entries = tuple(spec)
+        for c in sched.pool.caches.values():
+            for key, leaf in c.items():
+                entries = tuple(leaf.sharding.spec)
                 if key in M.PAGED_KV_KEYS:
                     assert entries[1] is None    # block dim replicated
-                    if len(entries) >= 4:
-                        assert entries[-2] == "model"
+                    assert entries[-1] == "model"
         assert sched.pool.block_tables.sharding.is_fully_replicated
         rids = [sched.submit([1, 2, 3, 4, 5], 6), sched.submit([9, 8], 4)]
         out = sched.run()
@@ -208,6 +205,32 @@ def test_paged_pool_under_mesh_matches_meshless(cfg, params):
                                                  prompt_bucket=8,
                                                  paged=True, block_size=8))
     rids2 = [plain.submit([1, 2, 3, 4, 5], 6), plain.submit([9, 8], 4)]
+    out2 = plain.run()
+    for ra, rb in zip(rids, rids2):
+        np.testing.assert_array_equal(out[ra], out2[rb])
+
+
+def test_paged_mqa_pool_under_mesh_stays_replicated(cfg):
+    """One KV head under model=2: "model" would split each (hkv * hd) row
+    inside the head, so the pool stays replicated (cache_pspecs), the
+    decode attention runs unsplit on every rank, and generations match
+    the meshless run."""
+    mqa = cfg.scaled(n_kv_heads=1)
+    params = M.init_params(mqa, jax.random.PRNGKey(0))
+    scfg = ServingConfig(max_batch=2, prompt_bucket=8, paged=True,
+                         block_size=8)
+    prompts = ([1, 2, 3, 4, 5], 6), ([9, 8], 4)
+    mesh = make_host_mesh(model=2)
+    with dctx.use_mesh(mesh):
+        sched = Scheduler(params, mqa, scfg, mesh=mesh)
+        for c in sched.pool.caches.values():
+            for key in M.PAGED_KV_KEYS:
+                if key in c:
+                    assert c[key].sharding.is_fully_replicated
+        rids = [sched.submit(p, n) for p, n in prompts]
+        out = sched.run()
+    plain = Scheduler(params, mqa, scfg)
+    rids2 = [plain.submit(p, n) for p, n in prompts]
     out2 = plain.run()
     for ra, rb in zip(rids, rids2):
         np.testing.assert_array_equal(out[ra], out2[rb])
@@ -319,3 +342,72 @@ def test_int8_kv_pages_with_scales(cfg, params):
         rid = sched.submit([1, 2, 3, 4, 5], 6)
         outs[paged] = sched.run()[rid]
     np.testing.assert_array_equal(outs[True], outs[False])
+
+
+@pytest.mark.parametrize("case", ["bf16", "window", "int8", "verify",
+                                  "trash"])
+def test_paged_append_matches_put_then_gather(cfg, wcfg, case):
+    """Each layer of the paged decode step writes its K/V rows into the
+    whole pool at its layer index and reads its blocks back
+    (``paged_append``, at the targets of ``paged_write_targets``).  Step
+    after step that equals writing the rows row by row and gathering the
+    slots' blocks, bit for bit: the attention's inputs at every row of a
+    slot's blocks, and the pool.  The steps cross block boundaries, wrap
+    the sliding-window ring, run speculative verify runs of 3 rows, and
+    write past a slot's capacity (rows for the trash block 0, whose
+    contents no query reads)."""
+    ns, bs, b = 2, 4, 3
+    c = wcfg if case == "window" else cfg
+    bps = c.window_ring_blocks(bs) or 6
+    lcap = bps * bs
+    nb = 2 * bps + 1
+    # slot 0 holds all its blocks, slot 1 the first four (sentinels after
+    # them), slot 2 none (an empty slot: every write goes to the trash)
+    table = np.zeros((b, bps), np.int32)
+    table[0] = np.arange(1, bps + 1)
+    table[1, :4] = np.arange(bps + 1, bps + 5)
+    s = 3 if case == "verify" else 1
+    pos = np.array({"window": [13, 2, 0], "trash": [lcap - 5, 9, 3]}
+                   .get(case, [2, 5, 0]), np.int32)
+    steps = 12 if case == "window" else 8
+    rng = np.random.default_rng(0)
+    if case == "int8":
+        leaves = {"k": (np.int8, (8,)), "k_scale": (np.float32, (2,))}
+    else:
+        leaves = {"k": (jnp.bfloat16, (8,))}
+
+    def draw(shape, dtype):
+        if dtype == np.int8:
+            return rng.integers(-127, 128, shape).astype(np.int8)
+        return rng.standard_normal(shape).astype(dtype)
+
+    pools = {k: draw((ns, nb, bs) + row, dt)
+             for k, (dt, row) in leaves.items()}
+    tables = jnp.asarray(table)
+    live = np.repeat(table != 0, bs, axis=1)      # rows queries may read
+    for _ in range(steps):
+        widx = (pos % lcap if c.sliding_window else pos)[:, None] \
+            + np.arange(s)
+        blk = np.where(widx < lcap,
+                       table[np.arange(b)[:, None],
+                             np.minimum(widx, lcap - 1) // bs], 0)
+        got = M.paged_write_targets(c, jnp.asarray(pos), s, tables, bs)
+        for want, g in zip((widx, blk, widx % bs), got):
+            np.testing.assert_array_equal(np.asarray(g), want)
+        for key, (dt, row) in leaves.items():
+            rows = draw((ns, b, s) + row, dt)
+            ref = pools[key].copy()
+            new = jnp.asarray(pools[key])
+            for li in range(ns):
+                for i in range(b):
+                    for j in range(s):
+                        ref[li, blk[i, j], widx[i, j] % bs] = rows[li, i, j]
+                new, view = M.paged_append(new, li, jnp.asarray(rows[li]),
+                                           got[1], got[2], tables)
+                want = ref[li][table].reshape((b, lcap) + row)
+                np.testing.assert_array_equal(np.asarray(view)[live],
+                                              want[live])
+            new = np.asarray(new)
+            np.testing.assert_array_equal(new[:, 1:], ref[:, 1:])
+            pools[key] = new
+        pos = pos + s

@@ -172,8 +172,13 @@ def test_fork_cow_divergent_tail():
     first = int(np.asarray(jnp.argmax(logits, -1))[0])
     div = (first + 1) % cfg.vocab_size  # forced divergent second branch
 
-    dec = jax.jit(lambda p, t, pos, act, c, bt: M.decode_step_slots(
-        p, t, pos, act, c, cfg, block_tables=bt))
+    # donated, as the scheduler's decode step: ``pool.caches`` is replaced
+    # by the result before anything reads it again
+    def step(p, t, pos, act, caches, bt):
+        return M.decode_step_slots(p, t, pos, act, caches, cfg,
+                                   block_tables=bt)
+
+    dec = M.serve_jit(step, donate="caches")
 
     def decode(pool, n_slots, firsts, steps=6):
         tokens = np.zeros((n_slots, 1), np.int32)

@@ -1,5 +1,6 @@
 """Share of the traced window in which no operation ran on the device, in %
-(profiler trace: 1 minus the union of the ``XLA Ops`` intervals)."""
+(profiler trace: 1 minus the union of the ``XLA Ops`` intervals; the window
+is cut where the device's record ends, ``servebench.trace``)."""
 
 
 def read(ctx, name):

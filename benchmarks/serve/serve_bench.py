@@ -4,7 +4,8 @@
     python3 benchmarks/serve/serve_bench.py --workload <name> --seed <n> \\
         --seconds <s> --trace <0|1>
 
-Makes the weights on the device from the seed, builds the program's
+Makes the weights on the cell's chips from the seed (split as the program
+splits them where the cell file gives a ``mesh``), builds the program's
 ``repro.serving.Scheduler`` as the cell says, warms up every prefill length
 the cell's traffic can draw, drives ``Scheduler.step()`` for ``--seconds``
 under the cell's traffic, checks the served tokens against the float32

@@ -7,6 +7,12 @@ no change to the program can change the yardstick.
 * ``kernel_calls``: the int8 matmuls of the ``quant`` lowering, one per
   linear per layer per call of a jitted step, with the rows that call
   passes (a decode step passes every slot; a prefill its padded prompt).
+  Under ``quant_tp`` over ``r`` chips of the "model" axis, each linear is
+  one call on every chip, of that chip's tile: ``(M, K/r, N)`` where the
+  program splits the weight's rows, else ``(M, K, ceil(N/r))``.  Which
+  one follows the program's ``dist.partitioning.tp_shard_dim`` rule, copied
+  here (``tile``) and held to it by a test.  With ``r = 1`` that is the
+  whole ``(M, K, N)``, once.
 * ``model_ops``: the operations of the tokens really processed, split by
   the arithmetic they run in: int8 linears, and the rest (attention's two
   products over each token's real context, the output table over each
@@ -51,9 +57,24 @@ def bucket(plen: int, prompt_bucket: int, window) -> int:
     return plen if window and b > window else b
 
 
+def tile(k: int, n: int, r: int) -> Tuple[int, int]:
+    """One chip's ``(K, N)`` of a ``(K, N)`` weight split over ``r`` chips
+    of the "model" axis.  The split dim is the larger of those that ``r``
+    divides, ties to ``N``; the rows only where ``K`` is that dim, else
+    the columns, padded up to a multiple of ``r``."""
+    if r == 1:
+        return k, n
+    n_fits = n % r == 0 and n >= r
+    if k % r == 0 and k >= r and (not n_fits or k > n):
+        return k // r, n
+    return k, -(-n // r)
+
+
 def kernel_calls(steps: Iterable[StepRecord], cfg, max_batch: int,
-                 prompt_bucket: int) -> Dict[Tuple[int, int, int], int]:
-    """(M, K, N) -> number of ``quant_matmul_int`` calls."""
+                 prompt_bucket: int, r: int = 1
+                 ) -> Dict[Tuple[int, int, int], int]:
+    """(M, K, N) -> number of ``quant_matmul_int`` calls, over all ``r``
+    chips that split each linear (one call per chip, of its tile)."""
     rows: Dict[int, int] = {}
     for s in steps:
         for plen in s.prefill_lens:
@@ -64,8 +85,8 @@ def kernel_calls(steps: Iterable[StepRecord], cfg, max_batch: int,
     out: Dict[Tuple[int, int, int], int] = {}
     for m, n_calls in rows.items():
         for k, n in linear_shapes(cfg):
-            key = (m, k, n)
-            out[key] = out.get(key, 0) + n_calls * cfg.n_layers
+            key = (m,) + tile(k, n, r)
+            out[key] = out.get(key, 0) + n_calls * cfg.n_layers * r
     return out
 
 

@@ -62,6 +62,15 @@ class CompileCounter:
             self.count += 1
 
 
+def _mesh(mesh):
+    """The program's mesh context, or none for a cell without a mesh."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    from repro.dist import context as dctx
+
+    return dctx.use_mesh(mesh)
+
+
 def _span(name: str, on: bool):
     if not on:
         return contextlib.nullcontext()
@@ -238,35 +247,39 @@ def main(argv=None, *, started: float, layout: Optional[spec.Layout] = None,
 
     cfg = spec.model_config(cell)
     scfg = spec.serving_config(cell)
-    params = weights.make_params(cfg, args.seed)
+    mesh = spec.make_mesh(cell, devs)
+    params = weights.make_params(cfg, args.seed, mesh=mesh)
     jax.block_until_ready(params)
     marks.append(("weights", time.monotonic()))
-    sched = Scheduler(params, cfg, scfg)
-    plan = traffic.make_plan(cell.traffic, args.seed, cfg.vocab_size,
-                             args.seconds)
-    marks.append(("scheduler and plan", time.monotonic()))
-    n_warm = warm_up(sched, cell.traffic, cfg, scfg)
-    marks.append(("warm-up", time.monotonic()))
-    counter = CompileCounter()
-    drv = Driver(sched, plan, cfg.sliding_window, spans=bool(args.trace))
+    with _mesh(mesh):
+        sched = Scheduler(params, cfg, scfg, mesh=mesh)
+        plan = traffic.make_plan(cell.traffic, args.seed, cfg.vocab_size,
+                                 args.seconds)
+        marks.append(("scheduler and plan", time.monotonic()))
+        n_warm = warm_up(sched, cell.traffic, cfg, scfg)
+        marks.append(("warm-up", time.monotonic()))
+        counter = CompileCounter()
+        drv = Driver(sched, plan, cfg.sliding_window, spans=bool(args.trace))
 
-    tdir = None
-    if args.trace:
-        tdir = tempfile.mkdtemp(prefix="serve_bench_trace_")
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(tdir, profiler_options=opts)
-    counter.armed = True
-    setup_s = time.monotonic() - started
-    with _span("bench.window", bool(args.trace)):
-        drv.window_loop(args.seconds)
-    counter.armed = False
-    if args.trace:      # the drain serves untraced
-        t_stop = time.monotonic()
-        jax.profiler.stop_trace()
-        _log(f"trace written in {time.monotonic() - t_stop:.1f} s")
-    drv.drain()
+        tdir = None
+        if args.trace:
+            tdir = tempfile.mkdtemp(prefix="serve_bench_trace_")
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(tdir, profiler_options=opts)
+        counter.armed = True
+        setup_s = time.monotonic() - started
+        with _span("bench.window", bool(args.trace)):
+            drv.window_loop(args.seconds)
+        counter.armed = False
+        if args.trace:      # the drain serves untraced
+            t_stop = time.monotonic()
+            jax.profiler.stop_trace()
+            _log(f"trace written in {time.monotonic() - t_stop:.1f} s")
+        drv.drain()
     run = drv.run
+    weight_devices = {len(leaf.sharding.device_set)
+                      for leaf in jax.tree_util.tree_leaves(params)}
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devs[:cell.chips])
     admits = _admits(sched, list(run.requests))
@@ -283,16 +296,23 @@ def main(argv=None, *, started: float, layout: Optional[spec.Layout] = None,
 
     summary = None
     if tdir is not None:
+        t_read = time.monotonic()
         summary = trace.load(tdir)
         shutil.rmtree(tdir, ignore_errors=True)
+        _log(f"trace read in {time.monotonic() - t_read:.1f} s")
+    if summary is not None and summary.cut is not None:
+        _log(f"trace cut at {summary.window_s:.6f} s of the "
+             f"{summary.cut.opened[1] - summary.cut.opened[0]:.6f}-s window, "
+             f"where the device's record ends: {summary.cut.steps_kept} of "
+             f"{summary.cut.steps} steps kept")
 
     chk = cell.cell["check"]
     picked = check.sample(finished, args.seed, int(chk["sample"]))
     t_ref = time.monotonic()
-    nums = check.compare(cell.config, args.seed,
-                         [(plan.prompt(r.index), np.asarray(r.tokens))
-                          for r in picked],
-                         control=bool(args.control)) if picked else {}
+    seqs = [(plan.prompt(r.index), np.asarray(r.tokens)) for r in picked]
+    nums = check.compare(cell.config, args.seed, seqs,
+                         control=bool(args.control),
+                         reference_dir=layout.reference_dir) if seqs else {}
     _log(f"reference over {len(picked)} requests, "
          f"{nums.get('served_tokens', 0)} served tokens, longest "
          f"{max((r.prompt_len + r.output_len for r in picked), default=0)}"
@@ -310,8 +330,13 @@ def main(argv=None, *, started: float, layout: Optional[spec.Layout] = None,
     correct = all(c["value"] is not None and c["value"] <= c["limit"]
                   for c in checks.values())
 
+    readable = summary
+    if summary is not None and not summary.readable:
+        _log(f"the cut keeps {100 * summary.kept_share:.1f}% of the window, "
+             f"under {100 * trace.MIN_SHARE:.0f}%: no reader reads the trace")
+        readable = None
     ctx = Context(cell=cell, cfg=cfg, scfg=scfg, run=run, setup_s=setup_s,
-                  admits=admits, trace=summary, peaks=pk)
+                  admits=admits, trace=readable, peaks=pk)
     metrics = {}
     for m in (cell.per_layer if args.trace else cell.end_to_end):
         value = layout.metric_reader(m["name"])(ctx, m["name"])
@@ -325,6 +350,11 @@ def main(argv=None, *, started: float, layout: Optional[spec.Layout] = None,
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
         result["breakdown"] = {"device_ops": summary.top_ops(),
                                "idle_gaps": summary.idle_gaps()}
+        cut = summary.cut
+        result["trace_cut"] = None if cut is None else {
+            "kept_s": summary.window_s,
+            "window_s": cut.opened[1] - cut.opened[0],
+            "steps_kept": cut.steps_kept, "steps": cut.steps}
     result["checks"] = checks
     for rid, r in run.requests.items():
         if plan.loop == "open" and run.open <= r.due < run.close \
@@ -350,6 +380,9 @@ def main(argv=None, *, started: float, layout: Optional[spec.Layout] = None,
         phases.append(f"{name} {at - t:.2f}")
         t = at
     _log("setup by phase (s): " + ", ".join(phases))
+    if mesh is not None:
+        _log(f"mesh {dict(mesh.shape)}; weight leaves span "
+             f"{sorted(weight_devices)} devices")
     _log(f"{args.workload} seed {args.seed}: {len(run.steps)} steps, "
          f"{len(run.requests)} requests, warm-up buckets {n_warm}, setup "
          f"{setup_s:.2f} s, generator late p50 "

@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict
+from typing import Dict, Optional
 
 from servebench.spec import Layout
 
@@ -51,10 +51,14 @@ TINY_DANUBE = dict(TINY_QWEN, name="tiny-danube",
                    sliding_window=16)
 
 
-def smoke_layout(root: str, config: Dict, loop: str = "open") -> Layout:
+def smoke_layout(root: str, config: Dict, loop: str = "open",
+                 chips: int = 1, pim_mode: str = "quant",
+                 mesh: Optional[Dict[str, int]] = None) -> Layout:
     """Write a one-config, one-cell benchmark under ``root``; the cell is
-    ``tiny-open`` or ``tiny-closed`` by ``loop``.  Prompts of 4-24 tokens
-    and answers of 2-12 fit a 48-token horizon; a 16-token window wraps."""
+    ``tiny-open`` or ``tiny-closed`` by ``loop``, on ``chips`` devices, its
+    linears lowered by ``pim_mode``, over ``mesh`` where given.  Prompts of
+    4-24 tokens and answers of 2-12 fit a 48-token horizon; a 16-token
+    window wraps."""
     bench_dir = os.path.join(root, "bench")
     for sub in ("configs", "traffic", "cells"):
         os.makedirs(os.path.join(bench_dir, sub), exist_ok=True)
@@ -72,11 +76,13 @@ def smoke_layout(root: str, config: Dict, loop: str = "open") -> Layout:
         "configs/tiny.json": config,
         f"traffic/tiny-{loop}.json": mix,
         f"cells/{cell}.json": {
-            "pim_mode": "quant", "max_seq_len": 48,
+            "pim_mode": pim_mode, "max_seq_len": 48,
             "serving": {"max_batch": 4, "prompt_bucket": 16, "paged": True,
                         "block_size": 8},
             "check": {"sample": 4, "widest_gap": 0.5}},
     }
+    if mesh is not None:
+        files[f"cells/{cell}.json"]["mesh"] = mesh
     for rel, obj in files.items():
         with open(os.path.join(bench_dir, rel), "w") as f:
             json.dump(obj, f)
@@ -87,7 +93,7 @@ def smoke_layout(root: str, config: Dict, loop: str = "open") -> Layout:
                      "file": "bench/configs/tiny.json", "reduced": [],
                      "why": "test"}],
         "workloads": [{"name": cell, "config": "tiny",
-                       "traffic": f"tiny-{loop}", "chips": 1,
+                       "traffic": f"tiny-{loop}", "chips": chips,
                        "why": "test"}],
         "end_to_end": E2E, "per_layer": PER_LAYER,
     }

@@ -11,6 +11,13 @@ host and device events share one clock.
 
 Busy time is the union of the ``XLA Ops`` intervals inside the window,
 per device, averaged over the devices.
+
+The profiler stops keeping events once it holds a fixed number of them, so
+a long busy window can lose its tail: the device's record ends while
+``bench.step`` spans run on.  The window is then cut once, here, where the
+record ends or where the first step it does not wholly hold starts, if that
+is earlier; every reader reads the cut window and the steps that start in
+it.  ``TraceSummary.cut`` records the cut.
 """
 from __future__ import annotations
 
@@ -22,27 +29,66 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 WINDOW = "bench.window"
+STEP = "bench.step"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 #: ops whose interval spans the ops of their body
 CONTAINERS = ("while", "conditional", "call")
+#: the least share of the window a cut may keep for the readers to read it
+MIN_SHARE = 0.5
 
 Interval = Tuple[float, float]
 
 
+@dataclasses.dataclass(frozen=True)
+class Cut:
+    """Where the window was cut, and the ``bench.step`` spans before it."""
+
+    opened: Interval            # the whole ``bench.window`` span
+    steps_kept: int             # steps that start before the cut
+    steps: int                  # steps that start in the whole window
+
+
 @dataclasses.dataclass
 class TraceSummary:
-    window: Interval            # the ``bench.window`` span, seconds
+    #: the ``bench.window`` span, seconds; its end is the cut, if any
+    window: Interval
     #: (program, op name) -> [device seconds, count] inside the window,
     #: summed over the devices
     ops: Dict[Tuple[str, str], List[float]]
     host: List[Tuple[str, float, float]]   # host spans of the main thread
     busy: List[List[Interval]]  # per device, merged
+    cut: Optional[Cut] = None
 
     @property
     def window_s(self) -> float:
         return self.window[1] - self.window[0]
+
+    @property
+    def kept_share(self) -> float:
+        """The share of the ``bench.window`` span the window keeps."""
+        if self.cut is None:
+            return 1.0
+        lo, hi = self.cut.opened
+        return self.window_s / (hi - lo)
+
+    @property
+    def readable(self) -> bool:
+        """The window keeps at least ``MIN_SHARE`` of the span, so the
+        readers may read it."""
+        return self.kept_share >= MIN_SHARE
+
+    def window_steps(self, steps: list) -> Optional[list]:
+        """The run's window steps (one a ``bench.step`` span, in order)
+        that start in the window: all of them where it was not cut, else
+        the first ``cut.steps_kept``; ``None`` where the run's steps and the
+        trace's spans do not pair up."""
+        if self.cut is None:
+            return steps
+        if len(steps) != self.cut.steps:
+            return None
+        return steps[:self.cut.steps_kept]
 
     @property
     def busy_s(self) -> float:
@@ -120,6 +166,21 @@ def _events(line):
         yield e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9
 
 
+def record_cut(host, ends: List[float], window: Interval) -> Optional[float]:
+    """Where to cut the window: the earliest of the devices' last op ends
+    (``ends``), or the start of the first step that ends after it, if that
+    is earlier; ``None`` where no ``bench.step`` span of the window starts
+    after the device's record ends."""
+    if not ends:
+        return None
+    end = min(ends)
+    lo, hi = window
+    steps = [(s, e) for n, s, e in host if n == STEP and lo <= s < hi]
+    if not any(s >= end for s, _ in steps):
+        return None
+    return min([end] + [s for s, e in steps if e > end])
+
+
 def reduce_profile(pd) -> Optional[TraceSummary]:
     """A :class:`TraceSummary` of a ``jax.profiler.ProfileData``; ``None``
     when the trace holds no ``bench.window`` span."""
@@ -135,10 +196,7 @@ def reduce_profile(pd) -> Optional[TraceSummary]:
                 window = next((s, e) for n, s, e in evs if n == WINDOW)
     if window is None:
         return None
-    lo, hi = window
-    ops: Dict[Tuple[str, str], List[float]] = collections.defaultdict(
-        lambda: [0.0, 0])
-    busy = []
+    devices = []
     for plane in pd.planes:
         if not DEVICE_PLANE.match(plane.name):
             continue
@@ -148,9 +206,24 @@ def reduce_profile(pd) -> Optional[TraceSummary]:
         mods = sorted((s, e, module_name(n))
                       for n, s, e in _events(lines[MODULES_LINE])) \
             if MODULES_LINE in lines else []
+        devices.append((mods, sorted(_events(lines[OPS_LINE]),
+                                     key=lambda x: x[1])))
+    lo, hi = window
+    cut = record_cut(host, [max(e for _, _, e in evs)
+                            for _, evs in devices if evs], window)
+    cut_info = None
+    if cut is not None:
+        starts = [s for n, s, _ in host if n == STEP and lo <= s < hi]
+        cut_info = Cut(opened=window, steps_kept=sum(s < cut for s in starts),
+                       steps=len(starts))
+        hi = cut
+    ops: Dict[Tuple[str, str], List[float]] = collections.defaultdict(
+        lambda: [0.0, 0])
+    busy = []
+    for mods, evs in devices:
         spans = []
         j = 0
-        for n, s, e in sorted(_events(lines[OPS_LINE]), key=lambda x: x[1]):
+        for n, s, e in evs:
             if e <= lo or s >= hi:
                 continue
             s, e = max(s, lo), min(e, hi)
@@ -163,8 +236,8 @@ def reduce_profile(pd) -> Optional[TraceSummary]:
             spans.append((s, e))
         busy.append(merge(spans))
     host = [(n, s, e) for n, s, e in host if e > lo and s < hi]
-    return TraceSummary(window=window, ops=dict(ops), host=host,
-                        busy=busy)
+    return TraceSummary(window=(lo, hi), ops=dict(ops), host=host,
+                        busy=busy, cut=cut_info)
 
 
 def load(log_dir: str) -> Optional[TraceSummary]:

@@ -124,3 +124,71 @@ def test_peaks_are_keyed_by_device_kind():
     assert flops.peaks("TPU v5 lite")["int8_ops_per_s"] == 393e12
     with pytest.raises(KeyError):
         flops.peaks("cpu")
+
+
+@pytest.mark.parametrize("r", [1, 2, 4, 8])
+def test_tile_follows_the_programs_split(r):
+    from repro.kernels.quant_matmul.tp import tp_tile_shape
+
+    dims = [1, 2, 3, 4, 6, 8, 64, 80, 128, 640, 1024, 2560, 2816, 6144,
+            6912, 24576, 151936]
+    for k in dims:
+        for n in dims:
+            want = (k, n) if r == 1 else tp_tile_shape((k, n), r)
+            assert flops.tile(k, n, r) == want, (k, n, r)
+
+
+def test_one_chip_counts_are_unchanged():
+    import repro.configs as configs
+
+    cfg = configs.get("h2o-danube-1.8b")
+    steps = [StepRecord(0, 1, [100, 700], decode_rows=0),
+             StepRecord(1, 2, [], decode_rows=5)]
+    want = {}
+    for m, n_calls in ((128, 1), (704, 1), (32, 1)):
+        for k, n in flops.linear_shapes(cfg):
+            want[(m, k, n)] = want.get((m, k, n), 0) + n_calls * 24
+    assert flops.kernel_calls(steps, cfg, 32, 64) == want
+    assert flops.kernel_calls(steps, cfg, 32, 64, r=1) == want
+
+
+def test_four_chips_count_each_chips_tile():
+    import repro.configs as configs
+
+    cfg = configs.get("h2o-danube-1.8b")
+    got = flops.kernel_calls([StepRecord(0, 1, [], decode_rows=5)], cfg, 32,
+                             64, r=4)
+    # q and o (2560x2560, a tie) and the MLP's 2560x6912 split their
+    # columns; k and v (2560x640) and the 6912x2560 down projection their
+    # rows
+    assert got == {(32, 2560, 640): 4 * 24 * 2, (32, 640, 640): 4 * 24 * 2,
+                   (32, 2560, 1728): 4 * 24 * 2, (32, 1728, 2560): 4 * 24}
+    one = flops.kernel_calls([StepRecord(0, 1, [], decode_rows=5)], cfg, 32,
+                             64)
+    ops = sum(c * flops.call_cost(*k)[0] for k, c in got.items())
+    assert ops == sum(c * flops.call_cost(*k)[0] for k, c in one.items())
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mfu_is_over_all_the_cells_chips(chips):
+    import repro.configs as configs
+    from servebench import run, spec
+    from servebench.window import RunRecord
+
+    class Cell:
+        pass
+
+    cell = Cell()
+    cell.chips = chips
+    cfg = configs.get("qwen1.5-0.5b")
+    rec = RunRecord(open=0.0, close=10.0)
+    rec.steps = [StepRecord(0.0, 0.5, [300], decode_rows=0),
+                 StepRecord(0.5, 0.6, [], decode_rows=12, decode_ctx=3600)]
+    pk = flops.peaks("TPU v5 lite")
+    ctx = run.Context(cell=cell, cfg=cfg, scfg=None, run=rec, setup_s=1.0,
+                      admits={}, trace=None, peaks=pk)
+    got = spec.Layout().metric_reader("mfu.chat")(ctx, "mfu.chat")
+    int8, other = flops.model_ops(rec.steps, cfg)
+    one_chip = 100.0 * (int8 / pk["int8_ops_per_s"]
+                        + other / pk["bf16_flops_per_s"]) / 0.6
+    assert got == (one_chip if chips == 1 else pytest.approx(one_chip / 4))
